@@ -1,89 +1,79 @@
 """Autotune the batched XNOR-popcount kernel's TQ/TM block shapes.
 
-Closes the ROADMAP item: the ``TORR_TQ``/``TORR_TM`` env overrides (read
-once at import by ``repro.kernels.xnor_popcount_sim``) make the block-shape
-sweep a no-code-edit loop — so this benchmark runs each (tq, tm) candidate
-in a fresh subprocess (the only way to re-read the env), times the batched
-``packed_hamming_batched`` kernel on a multi-stream-shaped workload, and
+Times the batched ``packed_hamming_batched`` kernel on a
+multi-stream-shaped workload for each (tq, tm) candidate — all in this one
+process, each candidate passed to the kernel explicitly (one process keeps
+one claim on the device; the kernel clips each candidate to a TPU-legal
+tile, and candidates that clip to the same tiles are timed once) — and
 emits the winning shapes as a JSON artifact::
 
     {"best": {"tq": .., "tm": ..}, "grid": [{"tq":..,"tm":..,"us":..}, ..],
-     "workload": {"N": .., "M": .., "D": ..}, "backend": "cpu-interpret"}
+     "workload": {"N": .., "M": .., "D": ..},
+     "device": {"platform": .., "kind": .., "count": ..}}
 
 Artifact path: ``TORR_AUTOTUNE_OUT`` env var, default
 ``autotune_blocks.json`` in the working directory. Point ``TORR_TUNE_FILE``
 at the written artifact and every kernel consumer (the direct defaults,
 ``kernels.ops``'s tile caps and the fused family) loads the swept winner at
 import — no hand-exported ``TORR_TQ``/``TORR_TM`` needed; explicit env vars
-still win (precedence table in ``kernels.xnor_popcount_sim``). On real TPU
-run the same sweep with a denser grid (the module docstring of
-``xnor_popcount_sim`` suggests TQ in {8,16,32} x TM in {128,256,512}); the
-defaults here are kept small so the CPU interpret-mode suite stays fast.
+still win (precedence table in ``kernels.xnor_popcount_sim``). A winner is
+a statement about the device in ``device``: on a TPU sweep a denser grid
+(the module docstring of ``xnor_popcount_sim`` suggests TQ in {8,16,32} x
+TM in {128,256,512}); the defaults here are kept small so a CPU run (the
+interpret-mode grid) stays fast.
 
 Rows: ``autotune/tq<tq>_tm<tm>, <us>, us`` per candidate plus
-``autotune/best, <us>, tq=..|tm=..``.
+``autotune/best, <us>, tq=..|tm=..|platform=..``.
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
+import time
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-# the child re-imports the kernel module under the swept env overrides and
-# prints one JSON line with the measured per-call latency
-_CHILD = """
-import json, time
 import jax
+
 from repro.core import hdc
-from repro.kernels.xnor_popcount_sim import (TM_DEFAULT, TQ_DEFAULT,
-                                             packed_hamming_batched)
-
-N, M, D = {N}, {M}, {D}
-q = hdc.pack_bits(hdc.random_hv(jax.random.PRNGKey(0), (N, D)))
-h = hdc.pack_bits(hdc.random_hv(jax.random.PRNGKey(1), (M, D)))
-fn = lambda: packed_hamming_batched(q, h)
-jax.block_until_ready(fn())              # compile
-t0 = time.perf_counter()
-iters = {iters}
-for _ in range(iters):
-    out = fn()
-jax.block_until_ready(out)
-us = (time.perf_counter() - t0) / iters * 1e6
-print(json.dumps(dict(tq=TQ_DEFAULT, tm=TM_DEFAULT, us=us)))
-"""
+from repro.kernels.xnor_popcount_sim import (lane_tile,
+                                             packed_hamming_batched,
+                                             sublane_tile)
 
 
-def _time_combo(tq: int, tm: int, N: int, M: int, D: int,
-                iters: int) -> dict:
-    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
-               TORR_TQ=str(tq), TORR_TM=str(tm))
-    code = _CHILD.format(N=N, M=M, D=D, iters=iters)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=600)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"autotune child (tq={tq}, tm={tm}) failed:\n{out.stderr[-2000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
+def _time_combo(q, h, tq: int, tm: int, iters: int) -> float:
+    """Per-call latency (us) of the kernel at explicit block shapes."""
+    fn = lambda: packed_hamming_batched(q, h, tq=tq, tm=tm)  # noqa: E731
+    jax.block_until_ready(fn())              # compile
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn()
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e6
 
 
-def run(tq_grid=(8, 16), tm_grid=(64, 128), N: int = 16, M: int = 256,
+def run(tq_grid=(8, 16), tm_grid=(128, 256), N: int = 16, M: int = 256,
         D: int = 4096, iters: int = 3) -> list[tuple]:
     """Sweep the grid, report each candidate, persist the best as JSON."""
-    grid = []
+    q = hdc.pack_bits(hdc.random_hv(jax.random.PRNGKey(0), (N, D)))
+    h = hdc.pack_bits(hdc.random_hv(jax.random.PRNGKey(1), (M, D)))
+    grid, seen = [], set()
     for tq in tq_grid:
         for tm in tm_grid:
-            r = _time_combo(tq, tm, N, M, D, iters)
-            grid.append(r)
+            tiles = (sublane_tile(N, tq), lane_tile(M, tm))
+            if tiles in seen:
+                continue
+            seen.add(tiles)
+            grid.append({"tq": tiles[0], "tm": tiles[1],
+                         "us": _time_combo(q, h, *tiles, iters)})
     best = min(grid, key=lambda r: r["us"])
 
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
     artifact = {
         "best": {"tq": best["tq"], "tm": best["tm"]},
         "grid": grid,
         "workload": {"N": N, "M": M, "D": D, "iters": iters},
-        "backend": "cpu-interpret",
+        "device": device,
     }
     out_path = os.environ.get("TORR_AUTOTUNE_OUT", "autotune_blocks.json")
     with open(out_path, "w") as f:
@@ -93,6 +83,7 @@ def run(tq_grid=(8, 16), tm_grid=(64, 128), N: int = 16, M: int = 256,
             for r in grid]
     rows.append(("autotune/best", round(best["us"], 1),
                  f"tq={best['tq']}|tm={best['tm']}|json={out_path}"
+                 f"|platform={device['platform']}|kind={device['kind']}"
                  "|apply_via=TORR_TUNE_FILE"))
     return rows
 

@@ -160,7 +160,7 @@ REUSE_CFG = TorrConfig(D=2048, B=8, M=1024, K=16, N_max=16,
 
 
 def _mix_trace(cfg: TorrConfig, mix: float, S: int, T: int, seed: int = 0,
-               numpy: bool = False):
+               numpy: bool = False, n_valid: int | None = None):
     """S streams x (T+1) windows at a fixed bypass/delta/full mix.
 
     Window 0 is the cold-cache warm-up (all full). From window 1 on, each
@@ -172,12 +172,15 @@ def _mix_trace(cfg: TorrConfig, mix: float, S: int, T: int, seed: int = 0,
     telemetry (LRU evictions pull a few intended hits back to full at
     middle mixes). The single reuse-mix synthesizer — the compact-dispatch
     bit-identity tests drive the same traces (``numpy=True`` returns host
-    arrays for the engine submit path).
+    arrays for the engine submit path). ``n_valid`` keeps only the first
+    proposals of each window valid (default all N_max): a cache of depth K
+    can only hold reuse for windows of at most K proposals.
     """
     rng = np.random.default_rng(seed)
     n_flip = max(1, cfg.D // 32)
     base = (rng.integers(0, 2, (S, cfg.N_max, cfg.D)) * 2 - 1).astype(np.int8)
-    valid = np.ones((S, cfg.N_max), bool)
+    valid = np.zeros((S, cfg.N_max), bool)
+    valid[:, :cfg.N_max if n_valid is None else n_valid] = True
     boxes = np.zeros((S, cfg.N_max, 4), np.float32)
     qd = np.full((S,), cfg.q_hi, np.int32)
     windows = []

@@ -64,6 +64,9 @@ def main() -> None:
                          "(e.g. table7,table8)")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (autotune_blocks, chaos_recovery, loadgen, micro_aligner,
                    roofline_summary, table1_hw, table2_envelope,
                    table3_runtime, table4_throughput, table5_accuracy,

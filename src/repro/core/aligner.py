@@ -157,7 +157,7 @@ def plan_prefix_hamming(
     use_kernel: bool = True,
 ) -> jax.Array:
     """Bank-prefix hamming over a (cap, planes) plan's enabled words:
-    int32 [N, M, cap]. Column selection + the ``bank_prefix_hamming``
+    int32 [N, cap, M]. Column selection + the ``bank_prefix_hamming``
     kernel; the batched multi-stream step hoists this single call over its
     flattened S x N_max proposal batch (one kernel pass per step — a
     per-stream call under vmap would re-enter the grid once per stream)."""
@@ -178,7 +178,7 @@ def full_scores_all(
     planes: int,               # static (latched plan)
     cap: int,                  # static plan cap on banks (cfg.B uncontrolled)
     mode: str = "switch",
-    ham_prefix: jax.Array | None = None,  # precomputed [N, M, cap] (hoisted)
+    ham_prefix: jax.Array | None = None,  # precomputed [N, cap, M] (hoisted)
     interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> jax.Array:
@@ -215,8 +215,8 @@ def full_scores_all(
         if ham_p is None:
             ham_p = plan_prefix_hamming(
                 q_packed_all, im, cfg, planes=planes, cap=cap,
-                interpret=interpret, use_kernel=use_kernel)  # [N, M, cap]
-        ham = ham_p[..., banks - 1]
+                interpret=interpret, use_kernel=use_kernel)  # [N, cap, M]
+        ham = ham_p[:, banks - 1, :]
         d_eff = cfg.d_eff_planned(banks, planes)
         return d_eff - 2 * ham
     if mode != "switch":
@@ -236,7 +236,7 @@ def full_scores_all(
 
 
 def prefix_select(
-    ham_prefix: jax.Array,     # int32 [..., M, cap] bank-boundary counts
+    ham_prefix: jax.Array,     # int32 [..., cap, M] bank-boundary counts
     banks: jax.Array,          # int32 [...] traced per-row bank choice
     planes: int,
     cfg: TorrConfig,
@@ -244,7 +244,7 @@ def prefix_select(
     """Accumulators from bank-prefix hamming counts: each row selects its
     traced bank boundary and normalizes by its own D'. int32 [..., M]."""
     ham = jnp.take_along_axis(
-        ham_prefix, (banks - 1)[..., None, None], axis=-1)[..., 0]
+        ham_prefix, (banks - 1)[..., None, None], axis=-2)[..., 0, :]
     d_eff = cfg.d_eff_planned(banks, planes)
     return d_eff[..., None] - 2 * ham
 
@@ -288,7 +288,7 @@ def compact_full_scores(
         safe = jnp.minimum(rows, R - 1)
         ham_b = plan_prefix_hamming(
             q_flat[safe], im, cfg, planes=planes, cap=cap,
-            interpret=interpret, use_kernel=use_kernel)     # [cap_b, M, cap]
+            interpret=interpret, use_kernel=use_kernel)     # [cap_b, cap, M]
         acc_b = prefix_select(ham_b, banks_flat[safe], planes, cfg)
         return jnp.zeros((R, cfg.M), jnp.int32).at[rows].set(
             acc_b, mode="drop")
@@ -296,7 +296,7 @@ def compact_full_scores(
     def hoisted():
         ham = plan_prefix_hamming(
             q_flat, im, cfg, planes=planes, cap=cap,
-            interpret=interpret, use_kernel=use_kernel)     # [R, M, cap]
+            interpret=interpret, use_kernel=use_kernel)     # [R, cap, M]
         acc = prefix_select(ham, banks_flat, planes, cfg)
         return jnp.where(full_mask[:, None], acc, 0)
 

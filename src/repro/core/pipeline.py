@@ -266,9 +266,12 @@ def _apply_pass_batched(state: TorrState, im: ItemMemory, q_packed_all,
 
     s_all = al.readout(acc_res, d_eff[:, None, None])        # [S, N, M]
     vals, kidx = jax.lax.top_k(s_all.reshape(S * N, M), cfg.top_k)
-    # without this barrier XLA-CPU sees the sliced/reshaped consumers and
-    # re-lowers TopK as a full row sort — ~5x the whole pass at M = 1024
-    vals, kidx = jax.lax.optimization_barrier((vals, kidx))
+    # without these barriers XLA-CPU sees the sliced/reshaped consumers and
+    # re-lowers TopK as a full row sort — ~5x the whole pass at M = 1024.
+    # One barrier per output: a single barrier over the (vals, kidx) tuple
+    # crashes XLA-CPU's TopK decomposer inside a shard_map.
+    vals = jax.lax.optimization_barrier(vals)
+    kidx = jax.lax.optimization_barrier(kidx)
     key_all = kidx.astype(jnp.int32).reshape(S, N, cfg.top_k)
     margin_all = (vals[:, 0] - vals[:, 1]).reshape(S, N)
     cached_key = jnp.where(
@@ -570,7 +573,7 @@ def torr_window_step(
     cfg: TorrConfig,
     plan=None,                 # static KnobPlan (None = uncontrolled)
     fused=None,                # static: "switch" | "prefix" | "compact" | "off"
-    ham_prefix_all=None,       # int32 [N_max, M, cap] hoisted prefix counts
+    ham_prefix_all=None,       # int32 [N_max, cap, M] hoisted prefix counts
     bucket_cap=None,           # static compact-dispatch bucket capacity
     decide=None,               # static: "batched" | "scan" (compact only)
 ) -> tuple[TorrState, WindowOutput, WindowTelemetry]:
@@ -797,7 +800,7 @@ def torr_multi_stream_step(
         S, N, W = q_packed_all.shape
         ham_prefix = al.plan_prefix_hamming(
             q_packed_all.reshape(S * N, W), im, cfg, planes=planes, cap=cap,
-        ).reshape(S, N, cfg.M, cap)
+        ).reshape(S, N, cap, cfg.M)
 
     if serial:
         def body(args):

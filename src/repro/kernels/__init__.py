@@ -17,8 +17,8 @@ from . import fused_window, ops, ref
 from .delta_update import delta_update
 from .fused_window import bank_prefix_hamming, fused_scores, sign_project_pack
 from .sign_project import sign_project
-from .xnor_popcount_sim import packed_hamming, packed_hamming_batched
+from .xnor_popcount_sim import packed_hamming_batched
 
 __all__ = ["fused_window", "ops", "ref", "delta_update", "sign_project",
-           "packed_hamming", "packed_hamming_batched", "fused_scores",
+           "packed_hamming_batched", "fused_scores",
            "bank_prefix_hamming", "sign_project_pack"]

@@ -61,7 +61,8 @@ from jax.experimental import pallas as pl
 
 from . import ref
 from .delta_update import delta_update as _delta_kernel
-from .xnor_popcount_sim import TM_DEFAULT, TQ_DEFAULT, TW, fit_tile
+from .xnor_popcount_sim import (TM_DEFAULT, TQ_DEFAULT, TW, fit_tile,
+                                lane_tile, resolve_interpret, sublane_tile)
 
 _I32_MIN = -(2 ** 31)
 _I32_MAX = 2 ** 31 - 1
@@ -81,13 +82,6 @@ def _pallas_lowering(interpret: bool | None) -> bool | None:
     if os.environ.get("TORR_FUSED_PALLAS", ""):
         return True
     return None
-
-
-def resolve_interpret(interpret: bool | None) -> bool:
-    """None -> interpret off-TPU only (the BlockSpecs are TPU-shaped)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -159,9 +153,9 @@ def fused_scores(
     N, W = q_packed.shape
     M, W2 = im_packed.shape
     assert W == W2, (W, W2)
-    tq = fit_tile(N, TQ_DEFAULT if tq is None else tq)
-    tm = fit_tile(M, TM_DEFAULT if tm is None else tm)
-    tw = fit_tile(W, tw)
+    tq = sublane_tile(N, TQ_DEFAULT if tq is None else tq)
+    tm = lane_tile(M, TM_DEFAULT if tm is None else tm)
+    tw = lane_tile(W, tw)     # a reduced plan's unaligned W is taken whole
     nw = W // tw
     kern = functools.partial(_fused_kernel, d_eff=d_eff, nw=nw, tm=tm)
     acc, best, top2 = pl.pallas_call(
@@ -231,21 +225,28 @@ def fused_scores_any(
 # bank-prefix hamming (traced-banks family member)
 # ---------------------------------------------------------------------------
 
-_PREFIX_VMEM_BUDGET = 4 * 1024 * 1024   # xor-tile bytes cap (VMEM is ~16 MB)
-
-
 def _prefix_kernel(q_ref, im_ref, out_ref, *, cap: int, epw: int):
-    """One (query-tile, class-tile) block per program: the xor against the
-    whole plan-capped word prefix stays in VMEM/registers, the per-bank
-    popcount reduce + running prefix sum happen in-register, and only the
-    tiny ``[TQ, TM, cap]`` prefix counts are written out. Bank boundaries
-    never constrain the tiling because banks are reduced *inside* the
-    block, not across grid steps."""
-    x = jnp.bitwise_xor(q_ref[...][:, None, :], im_ref[...][None, :, :])
-    pc = jax.lax.population_count(x).astype(jnp.int32)      # [TQ, TM, W]
-    tq, tm, _ = pc.shape
-    per_bank = jnp.sum(pc.reshape(tq, tm, cap, epw), axis=-1)
-    out_ref[...] = jnp.cumsum(per_bank, axis=-1)
+    """One (query-tile, class-tile) block per program: each query row's xor
+    against the item-memory tile stays in VMEM, and one small matmul with a
+    0/1 bank-prefix matrix ``tri[b, w] = (w < (b + 1) * epw)`` turns the
+    per-word popcounts into every bank boundary's running count, written
+    class-minor as ``[TQ, cap, TM]``. Neither a per-bank reduce that splits
+    the lane dimension nor a cumsum is needed, and bank boundaries never
+    constrain the tiling. Exact: popcounts (<= 32) and 0/1 are exact in
+    bf16, and the f32 accumulation of sums <= 32 * W < 2**24 is exact."""
+    w = im_ref.shape[1]
+    word = jax.lax.broadcasted_iota(jnp.int32, (cap, w), 1)
+    bank = jax.lax.broadcasted_iota(jnp.int32, (cap, w), 0)
+    tri = (word < (bank + 1) * epw).astype(jnp.bfloat16)    # [cap, W]
+    im = im_ref[...]                                        # [TM, W]
+    for i in range(q_ref.shape[0]):
+        x = jnp.bitwise_xor(q_ref[i:i + 1, :], im)          # [TM, W]
+        pc = jax.lax.population_count(x).astype(jnp.int32)
+        counts = jax.lax.dot_general(
+            tri, pc.astype(jnp.float32).astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [cap, TM]
+        out_ref[i] = counts.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -259,41 +260,41 @@ def bank_prefix_hamming(
     tm: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Hamming over the first 1..cap banks' enabled words: int32 [N, M, cap].
+    """Hamming over the first 1..cap banks' enabled words: int32 [N, cap, M].
 
     One pass over the plan-capped prefix (bytes read scale with the *static*
     cap x planes, never the full width); a traced per-window bank choice
-    selects its slice afterwards with one last-axis gather, which is what
-    keeps the jitted multi-stream path exact without executing a
+    selects its row afterwards with one gather over the ``cap`` axis, which
+    is what keeps the jitted multi-stream path exact without executing a
     ``lax.switch`` branch per bank per batch. ``N`` is typically the
     *flattened* proposal batch of a whole multi-stream step (S x N_max
     rows) — the batched engines hoist this single call out of their vmap,
     so each item-memory tile is read once per query block instead of once
-    per stream.
+    per stream. The class axis is last so the output is lane-dense.
 
-    The class tile clips so the in-VMEM xor block (tq x tm x W x 4B) stays
-    under a conservative budget; Pallas double-buffers the item-memory
-    tiles across grid steps as usual.
+    A word count that is not a multiple of the 128-lane width is zero-padded
+    to one (zero words xor to zero and add nothing).
     """
     N, W = q_packed.shape
     M, W2 = im_packed.shape
     assert W == W2 and W % cap == 0, (W, W2, cap)
     epw = W // cap                      # enabled words per bank
-    tq = fit_tile(N, TQ_DEFAULT if tq is None else tq)
-    tm_cap = TM_DEFAULT if tm is None else tm
-    while tm_cap > 8 and tq * tm_cap * W * 4 > _PREFIX_VMEM_BUDGET:
-        tm_cap //= 2
-    tm = fit_tile(M, tm_cap)
+    pad = -W % TW
+    if pad:
+        q_packed = jnp.pad(q_packed, ((0, 0), (0, pad)))
+        im_packed = jnp.pad(im_packed, ((0, 0), (0, pad)))
+    tq = sublane_tile(N, TQ_DEFAULT if tq is None else tq)
+    tm = lane_tile(M, TM_DEFAULT if tm is None else tm)
     kern = functools.partial(_prefix_kernel, cap=cap, epw=epw)
     return pl.pallas_call(
         kern,
         grid=(N // tq, M // tm),
         in_specs=[
-            pl.BlockSpec((tq, W), lambda n, m: (n, 0)),
-            pl.BlockSpec((tm, W), lambda n, m: (m, 0)),
+            pl.BlockSpec((tq, W + pad), lambda n, m: (n, 0)),
+            pl.BlockSpec((tm, W + pad), lambda n, m: (m, 0)),
         ],
-        out_specs=pl.BlockSpec((tq, tm, cap), lambda n, m: (n, m, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, M, cap), jnp.int32),
+        out_specs=pl.BlockSpec((tq, cap, tm), lambda n, m: (n, 0, m)),
+        out_shape=jax.ShapeDtypeStruct((N, cap, M), jnp.int32),
         interpret=resolve_interpret(interpret),
     )(q_packed, im_packed)
 
@@ -316,7 +317,7 @@ def _blocked_prefix(
         return carry, jnp.cumsum(per_bank, -1)       # [tq, M, cap]
 
     _, hp = jax.lax.scan(body, jnp.int32(0), qt)
-    return hp.reshape(N, M, cap)
+    return jnp.swapaxes(hp.reshape(N, M, cap), 1, 2)
 
 
 def bank_prefix_hamming_any(
@@ -358,9 +359,7 @@ def delta_apply(
     M = acc.shape[0]
     lowering = _pallas_lowering(interpret)
     if use_kernel and M % 8 == 0 and lowering is not None:
-        tm = fit_tile(M, 128)
-        return _delta_kernel(acc, dmajor, idx, weight, tm=tm,
-                             interpret=lowering)
+        return _delta_kernel(acc, dmajor, idx, weight, interpret=lowering)
     return ref.delta_update_ref(acc, dmajor, idx, weight)
 
 
@@ -368,46 +367,53 @@ def delta_apply(
 # encode front-end: sign-projection fused with bit-packing
 # ---------------------------------------------------------------------------
 
-def _pack_kernel(z_ref, r_ref, out_ref):
-    y = jnp.dot(z_ref[...], r_ref[...].T,
-                preferred_element_type=jnp.float32)          # [TN, TD]
-    bits = (y >= 0.0).astype(jnp.uint32)
-    tn, td = bits.shape
-    bits = bits.reshape(tn, td // 32, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    out_ref[...] = jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
+def _pack_kernel(r_ref, z_ref, out_ref):
+    """Dims on sublanes, queries on lanes: ``y^T = R_tile z^T`` is [TD, TN];
+    each group of 32 consecutive dims (a sublane-aligned split) folds into
+    one word by shifting each sign bit to its position and summing the
+    disjoint bits — the int32 sum of disjoint powers of two is their OR,
+    bit 31 included."""
+    y = jax.lax.dot_general(r_ref[...], z_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [TD, TN]
+    td, tn = y.shape
+    bits = (y >= 0.0).astype(jnp.int32).reshape(td // 32, 32, tn)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (td // 32, 32, tn), 1)
+    words = jnp.sum(jnp.left_shift(bits, shifts), axis=1)       # [TD/32, TN]
+    out_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
-@functools.partial(jax.jit, static_argnames=("tn", "td", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def sign_project_pack(
     z: jax.Array,    # f32 [N, d]
     R: jax.Array,    # f32 [D, d]
     *,
-    tn: int = 8,
-    td: int = 256,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Packed query words uint32 [N, D//32] = pack(sign(z @ R.T)).
 
     Extends the ``sign_project`` kernel one stage further: the f32
-    projection *and* the int8 bipolar code both stay in VMEM; only the
+    projection *and* the bipolar code both stay in VMEM; only the
     1-bit/dim packed words are written back (a 32x cut on the
     encoder->aligner hand-off, previously left to XLA as a separate pass).
+    The kernel writes the words transposed, ``[D//32, N]``, so a block is
+    8 words (256 dims; all D/32 where D is not a multiple of 256) on
+    sublanes by 128 queries (or all of them) on lanes; the wrapper
+    transposes the small packed result back.
     """
     N, d = z.shape
     D, d2 = R.shape
     assert d == d2 and D % 32 == 0
-    tn = min(tn, N)
-    td = min(td, D)
-    assert N % tn == 0 and D % td == 0 and td % 32 == 0
-    return pl.pallas_call(
+    td = 256 if D % 256 == 0 else D
+    tn = lane_tile(N, TW)
+    words_t = pl.pallas_call(
         _pack_kernel,
-        grid=(N // tn, D // td),
+        grid=(D // td, N // tn),
         in_specs=[
-            pl.BlockSpec((tn, d), lambda n, dd: (n, 0)),
-            pl.BlockSpec((td, d), lambda n, dd: (dd, 0)),
+            pl.BlockSpec((td, d), lambda dd, n: (dd, 0)),
+            pl.BlockSpec((tn, d), lambda dd, n: (n, 0)),
         ],
-        out_specs=pl.BlockSpec((tn, td // 32), lambda n, dd: (n, dd)),
-        out_shape=jax.ShapeDtypeStruct((N, D // 32), jnp.uint32),
+        out_specs=pl.BlockSpec((td // 32, tn), lambda dd, n: (dd, n)),
+        out_shape=jax.ShapeDtypeStruct((D // 32, N), jnp.uint32),
         interpret=resolve_interpret(interpret),
-    )(z, R)
+    )(R, z)
+    return words_t.T

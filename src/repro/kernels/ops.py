@@ -3,8 +3,8 @@
 These are the entry points the rest of the framework uses. Each chooses the
 kernel when shapes are kernel-friendly and transparently falls back to the
 oracle otherwise (ragged shapes, tiny trailing dims), so callers never see a
-shape constraint. ``interpret`` defaults to True because this container runs
-on CPU; on TPU pass interpret=False (the BlockSpecs are TPU-shaped).
+shape constraint. ``interpret=None`` (the default) resolves by backend:
+compiled on TPU, interpret mode elsewhere; ``True``/``False`` force one.
 
 Bank gating contract: ``banks`` is a *static* int here. The controller's
 per-window bank choice is latched on the host (exactly like the ASIC's
@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from ..core.item_memory import plane_sel
 from . import fused_window, ref
 from .sign_project import sign_project as _sign_kernel
-from .xnor_popcount_sim import TM_DEFAULT, TQ_DEFAULT, TW, fit_tile as _tile
 from .xnor_popcount_sim import packed_hamming_batched as _ham_kernel
 
 
@@ -44,26 +43,16 @@ def _batched_hamming(
     q: jax.Array,           # uint32 [N, W_eff]
     h: jax.Array,           # uint32 [M, W_eff]
     *,
-    interpret: bool,
+    interpret: bool | None,
     use_kernel: bool,
 ) -> jax.Array:
     """Shared dispatch for every packed-hamming consumer (full-path scans
-    and cache-nearest lookups): the batched kernel when shapes tile, the
-    jnp oracle otherwise. In interpret mode the word tile clips to the
-    largest divisor of the enabled word count (<= TW), so sub-lane-width
-    D' (small-D configs, deep reduced plans) still rides the kernel; the
-    compiled TPU path keeps the lane-width requirement (the BlockSpecs
-    are TPU-shaped) and falls back to the oracle off lane alignment."""
-    M = h.shape[0]
-    words_eff = q.shape[1]
-    # tile caps honor the TORR_TQ/TORR_TM autotuning overrides (see the
-    # defaults table in kernels.xnor_popcount_sim)
-    lane_ok = interpret or words_eff % TW == 0
-    if use_kernel and M % 8 == 0 and lane_ok:
-        return _ham_kernel(q, h, tq=_tile(q.shape[0], TQ_DEFAULT),
-                           tm=_tile(M, TM_DEFAULT),
-                           tw=_tile(words_eff, TW),
-                           interpret=interpret)
+    and cache-nearest lookups): the batched kernel when M tiles, the jnp
+    oracle otherwise. The kernel clips its tiles to TPU-legal blocks (a
+    sub-lane-width D' — small-D configs, deep reduced plans — is read as
+    one whole-row word tile), honoring the TORR_TQ/TORR_TM overrides."""
+    if use_kernel and h.shape[0] % 8 == 0:
+        return _ham_kernel(q, h, interpret=interpret)
     return ref.packed_hamming_ref(q, h)
 
 
@@ -112,7 +101,7 @@ def packed_similarity(
     planes: int | None = None,
     plane_total: int = 4,
     pmajor: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Full-scan scores under the (banks, planes) plan's enabled dims.
@@ -142,7 +131,7 @@ def fused_similarity(
     planes: int | None = None,
     plane_total: int = 4,
     pmajor: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Host-latched entry to the *fused* window-step kernel
@@ -178,11 +167,8 @@ def encode_packed(
     N, _ = z.shape
     D, _ = R.shape
     lowering = fused_window._pallas_lowering(interpret)
-    if (use_kernel and lowering is not None
-            and D % 128 == 0 and N % 8 == 0):
-        td = 256 if D % 256 == 0 else 128
-        return fused_window.sign_project_pack(z, R, tn=8, td=td,
-                                              interpret=lowering)
+    if use_kernel and lowering is not None and D % 32 == 0:
+        return fused_window.sign_project_pack(z, R, interpret=lowering)
     return _encode_packed_jnp(z, R)
 
 
@@ -198,7 +184,7 @@ def cache_nearest(
     bank_words: int,
     planes: int | None = None,
     plane_total: int = 4,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Batched PSU nearest-match: every query vs every cache entry.
@@ -279,7 +265,7 @@ def sign_project(
     z: jax.Array,   # f32 [N, d]
     R: jax.Array,   # f32 [D, d]
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> jax.Array:
     """Fused bipolar projection; falls back to the oracle off-tile."""

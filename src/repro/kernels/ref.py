@@ -28,7 +28,7 @@ def fused_scores_ref(
 def bank_prefix_hamming_ref(
     q_packed: jax.Array, im_packed: jax.Array, *, cap: int
 ) -> jax.Array:
-    """int32 [N, M, cap] — `fused_window.bank_prefix_hamming` (materializes
+    """int32 [N, cap, M] — `fused_window.bank_prefix_hamming` (materializes
     the [N, M, W] xor; the kernel exists so the jitted path never does)."""
     N, W = q_packed.shape
     M = im_packed.shape[0]
@@ -36,7 +36,7 @@ def bank_prefix_hamming_ref(
     x = jnp.bitwise_xor(q_packed[:, None, :], im_packed[None, :, :])
     pc = jax.lax.population_count(x).astype(jnp.int32)          # [N, M, W]
     per_bank = pc.reshape(N, M, cap, epw).sum(axis=-1)          # [N, M, cap]
-    return jnp.cumsum(per_bank, axis=-1)
+    return jnp.swapaxes(jnp.cumsum(per_bank, axis=-1), 1, 2)
 
 
 def sign_project_pack_ref(z: jax.Array, R: jax.Array) -> jax.Array:

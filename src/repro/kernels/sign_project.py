@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .xnor_popcount_sim import resolve_interpret
+
 
 def _kernel(z_ref, r_ref, out_ref):
     y = jnp.dot(
@@ -33,7 +35,7 @@ def sign_project(
     *,
     tn: int = 8,
     td: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Bipolar int8 [N, D] = sign(z @ R.T)."""
     N, d = z.shape
@@ -52,5 +54,5 @@ def sign_project(
         ],
         out_specs=pl.BlockSpec((tn, td), lambda n, dd: (n, dd)),
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.int8),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(z, R)

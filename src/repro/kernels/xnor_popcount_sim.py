@@ -12,12 +12,16 @@ Grid: (query-tiles, class-tiles, word-tiles), word dim fastest so each
 Each program processes a TQ x TM block of the output — a *block of queries*
 per program rather than one row — which is what lets the multi-stream
 engine amortize the item-memory tile across S stream slots' proposals
-(S * N_max query rows per window batch). ``packed_hamming`` is the TQ=1
-specialization kept for single-stream callers.
+(S * N_max query rows per window batch).
 
 Block shapes: item-memory tile (TM, TW) uint32 in VMEM; TW is a multiple of
 128 (lane width), TM a multiple of 8 (sublane), TQ a small sublane-multiple
 (8 by default) so the TQ x TM x TW xor intermediate stays VMEM-resident.
+The TPU compiler accepts a block only where its last two dimensions divide
+by 8 and 128 or equal the array's: :func:`sublane_tile`/:func:`lane_tile`
+clip every tile of this package to that rule (a dimension that has no such
+divisor is taken whole), so interpret mode runs the exact grid the chip
+compiles.
 The M x TW tile is broadcast against TQ query rows — the analogue of the
 ASIC's column broadcast to W class lanes, repeated over a query block.
 
@@ -34,7 +38,7 @@ artifact, all read once at import. Precedence (highest first):
          | ``TORR_TUNE_FILE``  |         | clipped to divide M
          | artifact ``best.tm``|         |
     tw   | (fixed)             |     128 | word-tile = lane width; not
-         |                     |         | tunable (clipped to divide W)
+         |                     |         | tunable (whole row if W % 128)
 
 ``TORR_TUNE_FILE`` points at the JSON artifact written by
 ``benchmarks/autotune_blocks.py`` (``{"best": {"tq": .., "tm": ..}, ...}``),
@@ -112,6 +116,31 @@ def fit_tile(n: int, cap: int) -> int:
     return t
 
 
+def sublane_tile(n: int, cap: int) -> int:
+    """Row tile for a block's second-to-last dimension: the largest
+    multiple of 8 dividing ``n`` that is <= max(cap, 8), or ``n`` itself
+    when ``n`` is not a multiple of 8 (a whole-dimension block)."""
+    if n % 8:
+        return n
+    return 8 * fit_tile(n // 8, max(cap, 8) // 8)
+
+
+def lane_tile(n: int, cap: int) -> int:
+    """Tile for a block's last dimension: the largest multiple of the
+    128-lane width dividing ``n`` that is <= max(cap, 128), or ``n`` itself
+    when ``n`` is not lane-aligned (a whole-dimension block)."""
+    if n % TW:
+        return n
+    return TW * fit_tile(n // TW, max(cap, TW) // TW)
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """None -> interpret off-TPU only (the BlockSpecs are TPU-shaped)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 def _kernel(q_ref, im_ref, ham_ref):
     w = pl.program_id(2)
 
@@ -134,7 +163,7 @@ def packed_hamming_batched(
     tq: int | None = None,
     tm: int | None = None,
     tw: int = TW,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Hamming distance of every query to every class: int32 [N, M].
 
@@ -150,12 +179,11 @@ def packed_hamming_batched(
     N, W = q_packed.shape
     M, W2 = im_packed.shape
     assert W == W2, (W, W2)
-    # clip the requested (or env-default) block shapes to actual divisors,
-    # so any TORR_TQ/TORR_TM sweep value yields a runnable grid
-    tq = fit_tile(N, TQ_DEFAULT if tq is None else tq)
-    tm = fit_tile(M, TM_DEFAULT if tm is None else tm)
-    tw = min(tw, W)
-    assert W % tw == 0, (W, tw)
+    # clip the requested (or env-default) block shapes to TPU-legal
+    # divisors, so any TORR_TQ/TORR_TM sweep value yields a runnable grid
+    tq = sublane_tile(N, TQ_DEFAULT if tq is None else tq)
+    tm = lane_tile(M, TM_DEFAULT if tm is None else tm)
+    tw = lane_tile(W, tw)
 
     grid = (N // tq, M // tm, W // tw)
     return pl.pallas_call(
@@ -167,20 +195,6 @@ def packed_hamming_batched(
         ],
         out_specs=pl.BlockSpec((tq, tm), lambda n, m, w: (n, m)),
         out_shape=jax.ShapeDtypeStruct((N, M), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_packed, im_packed)
 
-
-@functools.partial(jax.jit, static_argnames=("tm", "tw", "interpret"))
-def packed_hamming(
-    q_packed: jax.Array,    # uint32 [N, W_eff]
-    im_packed: jax.Array,   # uint32 [M, W_eff]
-    *,
-    tm: int | None = None,
-    tw: int = TW,
-    interpret: bool = True,
-) -> jax.Array:
-    """Row-per-program variant: the TQ=1 specialization of the batched grid."""
-    return packed_hamming_batched(
-        q_packed, im_packed, tq=1, tm=tm, tw=tw, interpret=interpret
-    )

@@ -15,6 +15,10 @@ Examples:
     # with the energy governor) on top of RT-60 admission control:
     TORR_GOV_ENERGY_MJ=60 PYTHONPATH=src python -m repro.launch.serve \
         --torr-streams 8 --torr-frames 30 --rt RT-60 --governor
+    # the paper's edge deployment (D=8192, M=1024, K=8, N_max=128) behind
+    # the network gateway; the default --deployment is the CPU-sized toy:
+    PYTHONPATH=src python -m repro.launch.serve --deployment torr_edge \
+        --gateway-port 0 --async
 
 QoS control plane (``--governor``)
 ==================================
@@ -107,15 +111,17 @@ JSONL, Chrome trace) is still flushed before the process exits.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import signal
 import threading
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs import get, get_smoke
+from ..configs import DEPLOYMENTS, deployment, get, get_smoke
 from ..core.types import TorrConfig
 from ..models import transformer as tf
 from ..serving import reranker as rr
@@ -161,8 +167,12 @@ def run_torr_streams(n_streams: int, n_frames: int, n_slots: int = 0,
                      state_store: str = "", snapshot_every: int = 1,
                      fault_at: int | None = None,
                      fault_kind: str = "dispatcher",
-                     outputs_jsonl: str = ""):
+                     outputs_jsonl: str = "", deployment_name: str = "toy"):
     """Serve S synthetic TOOD streams through the batched window engine.
+
+    ``deployment_name`` selects the served :class:`TorrConfig` from
+    ``configs.DEPLOYMENTS`` ("toy" by default; "torr_edge" is the paper's
+    edge deployment).
 
     ``use_async`` routes through the dispatch/collect
     :class:`repro.serving.async_engine.AsyncStreamEngine`; ``mesh_devices``
@@ -209,8 +219,7 @@ def run_torr_streams(n_streams: int, n_frames: int, n_slots: int = 0,
     use_async = (use_async or bool(rt) or governor or mesh_devices != 0
                  or supervise)
 
-    # K >= N_max so a window cannot thrash its own cache out of reuse range
-    cfg = TorrConfig(D=2048, B=8, M=64, K=16, N_max=16, delta_budget=256)
+    cfg = deployment(deployment_name)
     world = ts.make_world(seed=0, M=cfg.M, d=cfg.feat_dim)
     sys_ = tp.build_system(world, cfg, seed=0)
     n_slots = n_slots or n_streams
@@ -521,36 +530,108 @@ def run_torr_streams(n_streams: int, n_frames: int, n_slots: int = 0,
     return result
 
 
-def run_torr_gateway(n_slots: int = 8, serial: bool = False, rt: str = "",
-                     governor: bool = False, fused: str | None = None,
-                     metrics_port: int | None = None, metrics_json: str = "",
-                     flight_jsonl: str = "", flight_capacity: int = 4096,
-                     trace_json: str = "", supervise: bool = False,
-                     state_store: str = "", snapshot_every: int = 1,
-                     fault_at: int | None = None,
-                     fault_kind: str = "dispatcher",
-                     gateway_port: int = 0, gateway_host: str = "127.0.0.1",
-                     gateway_rate: float = 200.0, gateway_burst: int = 100,
-                     gateway_deadline_ms: float = 2000.0,
-                     gateway_max_conns: int = 64,
-                     gateway_tenant_sessions: int = 8,
-                     run_seconds: float = 0.0,
-                     use_async: bool = True):
-    """Serve the TorR engine behind the network gateway until SIGTERM.
+@dataclasses.dataclass
+class GatewayStack:
+    """The engine stack behind the network gateway: the served config and
+    synthetic TOOD system, the observability tier, state store, the engine
+    (sync behind a :class:`SyncDriver`, async, or supervised) and the
+    :class:`repro.serving.gateway.Gateway` itself — built and warmed by
+    :func:`build_torr_gateway`, not yet listening. ``None`` marks a part
+    the flags did not arm."""
 
-    The same engine stack as :func:`run_torr_streams` — config, synthetic
-    TOOD world, observability tier, state store, chaos plan, supervisor —
-    but instead of driving synthetic streams in-process, the
-    :class:`repro.serving.gateway.Gateway` listens on
-    ``gateway_host:gateway_port`` (0 = ephemeral, printed as a
-    ``listening`` line that ``benchmarks/loadgen.py --spawn`` parses) and
-    clients open tenant sessions over real sockets. SIGINT/SIGTERM
-    triggers the graceful drain: stop accepting, flush in-flight
-    requests, close the engine, write every armed artifact, exit 0.
+    cfg: TorrConfig
+    world: Any                 # data.tood_synth world
+    sys_: Any                  # serving.tood_pipelines system
+    gw: Any                    # serving.gateway.Gateway
+    limits: Any                # serving.gateway.GatewayLimits
+    eng: Any                   # StreamEngine / AsyncStreamEngine
+    sup: Any                   # ServeSupervisor | None
+    driver: Any                # SyncDriver | None
+    registry: Any              # obs.MetricsRegistry | None
+    flight: Any                # obs.FlightRecorder | None
+    server: Any                # obs.MetricsServer | None
+    store: Any                 # state store | None
+    metrics_json: str
+    flight_jsonl: str
+    trace_json: str
 
-    ``run_seconds > 0`` bounds the serve window (tests); 0 serves until
-    a signal arrives.
-    """
+    def close(self, interrupted: bool = False) -> dict:
+        """Drain in-flight requests, close the engine, write every armed
+        artifact; returns the run summary."""
+        from ..runtime.fault import EngineDead
+
+        gw, limits = self.gw, self.limits
+        drained = gw.drain(timeout=max(10.0, 2 * limits.request_deadline_s))
+        gw.close()
+        summary = gw.summary()
+        print(f"[serve/gateway] drained={drained} "
+              f"sessions={summary['sessions']}")
+        eng, sup = self.eng, self.sup
+        if sup is not None:
+            try:
+                sup.close(drain=False)
+            except EngineDead:
+                pass
+            eng = sup.engine
+            s = sup.summary()
+            print(f"[serve/gateway] supervisor: restarts={s['restarts']} "
+                  f"replayed={s['windows_replayed']} "
+                  f"rerun={s['windows_rerun']} degraded={s['degraded']}")
+        elif self.driver is not None:
+            self.driver.close()
+        else:
+            try:
+                eng.close(drain=False)
+            except EngineDead:
+                pass
+
+        registry, flight = self.registry, self.flight
+        if registry is not None:
+            eng.flush_telemetry()
+            if self.server is not None:
+                self.server.close()
+            if self.metrics_json:
+                from ..obs import write_json_snapshot
+                write_json_snapshot(registry, self.metrics_json)
+                print(f"[serve/gateway] metrics snapshot -> "
+                      f"{self.metrics_json}")
+            if self.flight_jsonl:
+                n_rec = flight.dump_jsonl(self.flight_jsonl)
+                print(f"[serve/gateway] flight recorder: {n_rec} records -> "
+                      f"{self.flight_jsonl}")
+            if self.trace_json:
+                from ..obs import write_chrome_trace
+                n_ev = write_chrome_trace(flight.records(), self.trace_json)
+                print(f"[serve/gateway] chrome trace: {n_ev} events -> "
+                      f"{self.trace_json}")
+        if self.store is not None and hasattr(self.store, "close"):
+            self.store.close()
+        print(f"[serve/gateway] exit 0 (interrupted={interrupted})",
+              flush=True)
+        return {"registry": registry, "flight": flight, "drained": drained,
+                "summary": summary,
+                "supervisor": sup.summary() if sup is not None else None}
+
+
+def build_torr_gateway(n_slots: int = 8, serial: bool = False, rt: str = "",
+                       governor: bool = False, fused: str | None = None,
+                       metrics_port: int | None = None,
+                       metrics_json: str = "", flight_jsonl: str = "",
+                       flight_capacity: int = 4096, trace_json: str = "",
+                       supervise: bool = False, state_store: str = "",
+                       snapshot_every: int = 1, fault_at: int | None = None,
+                       fault_kind: str = "dispatcher",
+                       gateway_port: int = 0,
+                       gateway_host: str = "127.0.0.1",
+                       gateway_rate: float = 200.0, gateway_burst: int = 100,
+                       gateway_deadline_ms: float = 2000.0,
+                       gateway_max_conns: int = 64,
+                       gateway_tenant_sessions: int = 8,
+                       use_async: bool = True,
+                       deployment_name: str = "toy") -> GatewayStack:
+    """Build and warm the engine stack behind the network gateway. The
+    arguments are ``main``'s engine and ``--gateway-*`` flags; the caller
+    starts ``stack.gw`` and ends with ``stack.close()``."""
     from ..data import tood_synth as ts
     from ..serving import tood_pipelines as tp
     from ..serving.gateway import Gateway, GatewayLimits, SyncDriver
@@ -558,7 +639,7 @@ def run_torr_gateway(n_slots: int = 8, serial: bool = False, rt: str = "",
     supervise = supervise or fault_at is not None
     use_async = use_async or bool(rt) or governor or supervise
 
-    cfg = TorrConfig(D=2048, B=8, M=64, K=16, N_max=16, delta_budget=256)
+    cfg = deployment(deployment_name)
     world = ts.make_world(seed=0, M=cfg.M, d=cfg.feat_dim)
     sys_ = tp.build_system(world, cfg, seed=0)
 
@@ -644,16 +725,42 @@ def run_torr_gateway(n_slots: int = 8, serial: bool = False, rt: str = "",
                  metrics=registry, flight=flight)
     if server is not None and sup is None:
         server.set_ready(gw._front_health)
+    return GatewayStack(cfg=cfg, world=world, sys_=sys_, gw=gw,
+                        limits=limits, eng=eng,
+                        sup=sup, driver=driver, registry=registry,
+                        flight=flight, server=server, store=store,
+                        metrics_json=metrics_json, flight_jsonl=flight_jsonl,
+                        trace_json=trace_json)
 
+
+def run_torr_gateway(run_seconds: float = 0.0, gateway_host: str = "127.0.0.1",
+                     **kwargs):
+    """Serve the TorR engine behind the network gateway until SIGTERM.
+
+    The same engine stack as :func:`run_torr_streams` — config
+    (``deployment_name``), synthetic TOOD world, observability tier, state
+    store, chaos plan, supervisor — but instead of driving synthetic
+    streams in-process, the :class:`repro.serving.gateway.Gateway` listens
+    on ``gateway_host:gateway_port`` (0 = ephemeral, printed as a
+    ``listening`` line that ``benchmarks/loadgen.py --spawn`` parses) and
+    clients open tenant sessions over real sockets. SIGINT/SIGTERM
+    triggers the graceful drain: stop accepting, flush in-flight
+    requests, close the engine, write every armed artifact, exit 0.
+    ``kwargs`` are :func:`build_torr_gateway`'s.
+
+    ``run_seconds > 0`` bounds the serve window (tests); 0 serves until
+    a signal arrives.
+    """
+    stack = build_torr_gateway(gateway_host=gateway_host, **kwargs)
     interrupted = False
     prev_handlers = None
     try:
         prev_handlers = _install_signal_handlers()
-        gw.start()
+        stack.gw.start()
         # the loadgen --spawn handshake line: printed only once the
         # socket accepts (flush so a pipe reader sees it immediately)
         print(f"[serve/gateway] listening on "
-              f"http://{gateway_host}:{gw.port} "
+              f"http://{gateway_host}:{stack.gw.port} "
               f"(SIGINT/SIGTERM drains and flushes artifacts)", flush=True)
         t_end = None if run_seconds <= 0 else time.time() + run_seconds
         while t_end is None or time.time() < t_end:
@@ -664,53 +771,7 @@ def run_torr_gateway(n_slots: int = 8, serial: bool = False, rt: str = "",
     finally:
         if prev_handlers is not None:
             _restore_signal_handlers(prev_handlers)
-
-    drained = gw.drain(timeout=max(10.0, 2 * limits.request_deadline_s))
-    gw.close()
-    summary = gw.summary()
-    print(f"[serve/gateway] drained={drained} sessions={summary['sessions']}")
-    from ..runtime.fault import EngineDead
-    if sup is not None:
-        try:
-            sup.close(drain=False)
-        except EngineDead:
-            pass
-        eng = sup.engine
-        s = sup.summary()
-        print(f"[serve/gateway] supervisor: restarts={s['restarts']} "
-              f"replayed={s['windows_replayed']} rerun={s['windows_rerun']} "
-              f"degraded={s['degraded']}")
-    elif driver is not None:
-        driver.close()
-    elif use_async:
-        try:
-            eng.close(drain=False)
-        except EngineDead:
-            pass
-
-    if registry is not None:
-        eng.flush_telemetry()
-        if server is not None:
-            server.close()
-        if metrics_json:
-            from ..obs import write_json_snapshot
-            write_json_snapshot(registry, metrics_json)
-            print(f"[serve/gateway] metrics snapshot -> {metrics_json}")
-        if flight_jsonl:
-            n_rec = flight.dump_jsonl(flight_jsonl)
-            print(f"[serve/gateway] flight recorder: {n_rec} records -> "
-                  f"{flight_jsonl}")
-        if trace_json:
-            from ..obs import write_chrome_trace
-            n_ev = write_chrome_trace(flight.records(), trace_json)
-            print(f"[serve/gateway] chrome trace: {n_ev} events -> "
-                  f"{trace_json}")
-    if store is not None and hasattr(store, "close"):
-        store.close()
-    print(f"[serve/gateway] exit 0 (interrupted={interrupted})", flush=True)
-    return {"registry": registry, "flight": flight, "drained": drained,
-            "summary": summary,
-            "supervisor": sup.summary() if sup is not None else None}
+    return stack.close(interrupted)
 
 
 def _write_output(f, sid, seq, wout) -> None:
@@ -838,7 +899,16 @@ def main() -> None:
                     help="drive the sync StreamEngine through the "
                          "SyncDriver adapter instead of the async runtime "
                          "(incompatible with --rt/--governor/--supervise)")
+    ap.add_argument("--deployment", default="toy",
+                    choices=sorted(DEPLOYMENTS),
+                    help="served TorrConfig for --torr-streams and "
+                         "--gateway-port: toy (default; D=2048, M=64) or "
+                         "torr_edge (the paper's D=8192, M=1024, K=8, "
+                         "N_max=128)")
     args = ap.parse_args()
+
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.gateway_port is not None:
         run_torr_gateway(
@@ -856,7 +926,8 @@ def main() -> None:
             gateway_max_conns=args.gateway_max_conns,
             gateway_tenant_sessions=args.gateway_tenant_sessions,
             run_seconds=args.gateway_seconds,
-            use_async=not args.gateway_sync)
+            use_async=not args.gateway_sync,
+            deployment_name=args.deployment)
         return
 
     if args.torr_streams > 0:
@@ -876,7 +947,8 @@ def main() -> None:
                          snapshot_every=args.snapshot_every,
                          fault_at=args.fault_at,
                          fault_kind=args.fault_kind,
-                         outputs_jsonl=args.outputs_jsonl)
+                         outputs_jsonl=args.outputs_jsonl,
+                         deployment_name=args.deployment)
         return
 
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)

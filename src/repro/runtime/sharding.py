@@ -13,6 +13,7 @@ vmapped group stack.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -292,3 +293,28 @@ def stream_sharding(tree, mesh: Mesh):
 def replicated_sharding(tree, mesh: Mesh):
     """Fully-replicated NamedSharding tree (shared item memory)."""
     return jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
+
+
+def _stream_sharded_step(state, im, batch, cfg, serial=False, plan=None,
+                         fused=None, bucket_cap=None, decide=None, *, mesh):
+    """``pipeline.torr_stream_batch_step`` with each device stepping only
+    its own stream slots (``shard_map`` over the stream axis; the item
+    memory replicated). Streams never interact, so this is the same step
+    — and the Pallas kernels inside it, which XLA cannot partition, see
+    per-device blocks."""
+    from ..core import pipeline
+
+    body = functools.partial(pipeline.torr_stream_batch_step, cfg=cfg,
+                             serial=serial, plan=plan, fused=fused,
+                             bucket_cap=bucket_cap, decide=decide)
+    slots = P(STREAM_AXIS)
+    return jax.shard_map(body, mesh=mesh, in_specs=(slots, P(), slots),
+                         out_specs=slots, check_vma=False)(state, im, batch)
+
+
+# module-level jit (mesh static) so engines on the same mesh share one
+# compiled executable, like the unsharded engines' step
+stream_sharded_step = jax.jit(
+    _stream_sharded_step,
+    static_argnames=("cfg", "serial", "plan", "fused", "bucket_cap",
+                     "decide", "mesh"))

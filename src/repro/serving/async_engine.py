@@ -72,6 +72,7 @@ governor's EWMA energy estimate. With the governor pinned to the full plan
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -153,6 +154,10 @@ class AsyncStreamEngine(StreamEngine):
         self._sp_drain = sp("collector_drain")
         self._last_slack = None
         if self._mesh is not None:
+            # each device steps its own slots (the Pallas kernels cannot
+            # be partitioned by XLA, so the step is shard_mapped)
+            self._step = functools.partial(shd.stream_sharded_step,
+                                           mesh=self._mesh)
             # stacked per-stream state sharded on the slot axis; item memory
             # (shared task knowledge) replicated on every device
             self._state = jax.device_put(

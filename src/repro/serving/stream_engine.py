@@ -331,6 +331,12 @@ class StreamEngine:
                 break
         return q, v, b, qd, served
 
+    @property
+    def state(self) -> TorrState:
+        """The stacked per-slot state (placed over the mesh when the engine
+        shards its slots)."""
+        return self._state
+
     def set_plan(self, plan) -> None:
         """Latch a knob plan (``repro.control.plan.KnobPlan`` or None) for
         subsequent steps. Host-side only: takes effect on the next dispatch."""

@@ -111,7 +111,7 @@ def test_bank_prefix_hamming_grid(D, M, N, cap):
     imp, qp = hdc.pack_bits(hv), hdc.pack_bits(q)
     out = fused_window.bank_prefix_hamming(qp, imp, cap=cap, interpret=True)
     want = ref.bank_prefix_hamming_ref(qp, imp, cap=cap)
-    assert out.shape == (N, M, cap)
+    assert out.shape == (N, cap, M)
     assert np.array_equal(np.asarray(out), np.asarray(want))
 
 
