@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..obs.phases import phase
 from .item_memory import (ItemMemory, bank_plane_sel, pmajor_bank_blocks,
                           word_mask)
 from .types import TorrConfig
@@ -158,15 +159,18 @@ def plan_prefix_hamming(
 ) -> jax.Array:
     """Bank-prefix hamming over a (cap, planes) plan's enabled words:
     int32 [N, cap, M]. Column selection + the ``bank_prefix_hamming``
-    kernel; the batched multi-stream step hoists this single call over its
-    flattened S x N_max proposal batch (one kernel pass per step — a
-    per-stream call under vmap would re-enter the grid once per stream)."""
+    kernel, the step's ``prefix_scan`` phase; the batched multi-stream
+    step hoists this single call over its flattened S x N_max proposal
+    batch (one kernel pass per step — a per-stream call under vmap would
+    re-enter the grid once per stream)."""
     from ..kernels import fused_window as fw
 
-    q_sel, im_sel = _plan_columns_bank_major(q_packed, im, cap, planes, cfg)
-    return fw.bank_prefix_hamming_any(q_sel, im_sel, cap=cap,
-                                      interpret=interpret,
-                                      use_kernel=use_kernel)
+    with phase("prefix_scan"):
+        q_sel, im_sel = _plan_columns_bank_major(q_packed, im, cap, planes,
+                                                 cfg)
+        return fw.bank_prefix_hamming_any(q_sel, im_sel, cap=cap,
+                                          interpret=interpret,
+                                          use_kernel=use_kernel)
 
 
 def full_scores_all(

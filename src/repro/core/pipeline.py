@@ -41,6 +41,13 @@ oracle (``tests/test_decide_batched.py``).
 The returned :class:`WindowTelemetry` trace is the input to the
 cycle-accurate model (`repro.perf.cycle_model`), keeping the functional and
 timing models in lock-step by construction.
+
+Every lowering names its phases with the same named scopes
+(:data:`repro.obs.phases.PHASES`: ``policy``, ``full_select``,
+``nearest``, ``delta_search``, ``path_select``, ``delta_apply``,
+``reason``, ``cache_write``, ``finish``; ``prefix_scan`` in the aligner),
+so each op of the compiled step carries its phase in its metadata. Scopes
+change no op and no result.
 """
 from __future__ import annotations
 
@@ -50,6 +57,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from ..obs.phases import phase
 from . import aligner as al
 from . import policy, query_cache, reasoner
 from .item_memory import ItemMemory, plan_word_mask
@@ -122,31 +130,37 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
         else:
             q_packed, valid, i = inp
             lru = None
-            idx, rho, _ham = query_cache.nearest(cache, q_packed, cfg, banks,
-                                                 planes)
-            d_idx, d_weight, d_count = al.delta_indices(
-                q_packed, cache.packed[idx], wmask, cfg.delta_budget, cfg.D
-            )
-            # Eq. 6 exactness: the cached accumulator is only
-            # delta-correctable under the exact (banks, planes) it was
-            # computed with
-            tag_ok = cache.acc_tag[idx] == tag
-            action = policy.select_path(rho, d_count, tag_ok, high, cfg)
+            with phase("nearest"):
+                idx, rho, _ham = query_cache.nearest(cache, q_packed, cfg,
+                                                     banks, planes)
+            with phase("delta_search"):
+                d_idx, d_weight, d_count = al.delta_indices(
+                    q_packed, cache.packed[idx], wmask, cfg.delta_budget,
+                    cfg.D)
+            with phase("path_select"):
+                # Eq. 6 exactness: the cached accumulator is only
+                # delta-correctable under the exact (banks, planes) it
+                # was computed with
+                tag_ok = cache.acc_tag[idx] == tag
+                action = policy.select_path(rho, d_count, tag_ok, high, cfg)
 
         def bypass_branch(cache):
             out = cache.out[idx]
             return query_cache.touch(cache, idx), out, jnp.array(False)
 
         def delta_branch(cache):
-            if fused_delta:
-                acc = al.delta_apply(cache.acc[idx], im, d_idx, d_weight)
-            else:
-                acc = al.delta_correct(cache.acc[idx], im, d_idx, d_weight)
-            s = al.readout(acc, d_eff)
-            out, active, key, margin = reasoner.gate_and_apply(
-                s, task_w, cache.out[idx], cache.topk_key[idx],
-                cache.margin[idx], cfg,
-            )
+            with phase("delta_apply"):
+                if fused_delta:
+                    acc = al.delta_apply(cache.acc[idx], im, d_idx, d_weight)
+                else:
+                    acc = al.delta_correct(cache.acc[idx], im, d_idx,
+                                           d_weight)
+                s = al.readout(acc, d_eff)
+            with phase("reason"):
+                out, active, key, margin = reasoner.gate_and_apply(
+                    s, task_w, cache.out[idx], cache.topk_key[idx],
+                    cache.margin[idx], cfg,
+                )
             cache = query_cache.write_entry(
                 cache, idx, packed=q_packed, acc=acc, acc_tag=tag,
                 out=out, topk_key=key, margin=margin,
@@ -154,15 +168,17 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
             return cache, out, active
 
         def full_branch(cache):
-            if acc_full_all is None:
-                acc = al.full_dot(q_packed, im, wmask)
-            else:
-                acc = acc_full_all[i]
-            s = al.readout(acc, d_eff)
-            out, active, key, margin = reasoner.gate_and_apply(
-                s, task_w, cache.out[idx], cache.topk_key[idx],
-                cache.margin[idx], cfg,
-            )
+            with phase("full_select"):
+                if acc_full_all is None:
+                    acc = al.full_dot(q_packed, im, wmask)
+                else:
+                    acc = acc_full_all[i]
+            with phase("reason"):
+                s = al.readout(acc, d_eff)
+                out, active, key, margin = reasoner.gate_and_apply(
+                    s, task_w, cache.out[idx], cache.topk_key[idx],
+                    cache.margin[idx], cfg,
+                )
             slot = query_cache.lru_slot(cache) if lru is None else lru
             cache = query_cache.write_entry(
                 cache, slot, packed=q_packed, acc=acc, acc_tag=tag,
@@ -178,12 +194,18 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
             eff_action = action       # the decide pass already padded it
             d_count_t, rho_t = d_count, rho
         else:
-            eff_action = jnp.where(valid, action, jnp.int32(3))
-            d_count_t = jnp.where(valid, d_count, 0)
-            rho_t = jnp.where(valid, rho, 0.0)
-        cache, out, active = jax.lax.switch(
-            eff_action, [bypass_branch, delta_branch, full_branch, pad_branch], cache
-        )
+            with phase("path_select"):
+                eff_action = jnp.where(valid, action, jnp.int32(3))
+                d_count_t = jnp.where(valid, d_count, 0)
+                rho_t = jnp.where(valid, rho, 0.0)
+        # the cache's touch and writes are the cache_write phase, and so,
+        # under vmap, are the branches' operands broadcast to every lane
+        # and the select that merges their results; the branches' own
+        # scopes name their value math
+        with phase("cache_write"):
+            cache, out, active = jax.lax.switch(
+                eff_action,
+                [bypass_branch, delta_branch, full_branch, pad_branch], cache)
         telem = (eff_action, d_count_t, rho_t, active)
         return cache, (out, telem)
 
@@ -242,83 +264,87 @@ def _apply_pass_batched(state: TorrState, im: ItemMemory, q_packed_all,
 
     d_eff = cfg.d_eff_planned(jnp.asarray(banks, jnp.int32), planes)  # [S]
     tag = jnp.asarray(plan_tag(banks, planes), jnp.int32)             # [S]
-    corr = al.delta_corrections(
-        d_idx.reshape(S * N, -1), d_weight.reshape(S * N, -1), im, cfg.D
-    ).reshape(S, N, M)
-
-    # each proposal's snapshot view of its nearest slot
-    snap_acc = jnp.take_along_axis(cache.acc, idx[..., None], axis=1)
-    snap_out = jnp.take_along_axis(cache.out, idx[..., None], axis=1)
-    snap_key = jnp.take_along_axis(cache.topk_key, idx[..., None], axis=1)
-    snap_margin = jnp.take_along_axis(cache.margin, idx, axis=1)
     s_ix = jnp.arange(S)
     src_safe = jnp.maximum(src, 0)
+    with phase("delta_apply"):
+        corr = al.delta_corrections(
+            d_idx.reshape(S * N, -1), d_weight.reshape(S * N, -1), im, cfg.D
+        ).reshape(S, N, M)
+        # each proposal's snapshot view of its nearest slot
+        snap_acc = jnp.take_along_axis(cache.acc, idx[..., None], axis=1)
 
-    def acc_body(acc_res, i):
-        read = jnp.where(src[:, i, None] < 0, snap_acc[:, i],
-                         acc_res[s_ix, src_safe[:, i]])
-        acc_i = jnp.where(is_full[:, i, None], acc_rows[:, i],
-                          read + corr[:, i])
-        return acc_res.at[:, i].set(acc_i), None
+        def acc_body(acc_res, i):
+            read = jnp.where(src[:, i, None] < 0, snap_acc[:, i],
+                             acc_res[s_ix, src_safe[:, i]])
+            acc_i = jnp.where(is_full[:, i, None], acc_rows[:, i],
+                              read + corr[:, i])
+            return acc_res.at[:, i].set(acc_i), None
 
-    acc_res, _ = jax.lax.scan(acc_body, jnp.zeros((S, N, M), jnp.int32),
-                              jnp.arange(N))
+        acc_res, _ = jax.lax.scan(acc_body, jnp.zeros((S, N, M), jnp.int32),
+                                  jnp.arange(N))
 
-    s_all = al.readout(acc_res, d_eff[:, None, None])        # [S, N, M]
-    vals, kidx = jax.lax.top_k(s_all.reshape(S * N, M), cfg.top_k)
-    # without these barriers XLA-CPU sees the sliced/reshaped consumers and
-    # re-lowers TopK as a full row sort — ~5x the whole pass at M = 1024.
-    # One barrier per output: a single barrier over the (vals, kidx) tuple
-    # crashes XLA-CPU's TopK decomposer inside a shard_map.
-    vals = jax.lax.optimization_barrier(vals)
-    kidx = jax.lax.optimization_barrier(kidx)
-    key_all = kidx.astype(jnp.int32).reshape(S, N, cfg.top_k)
-    margin_all = (vals[:, 0] - vals[:, 1]).reshape(S, N)
-    cached_key = jnp.where(
-        src[..., None] < 0, snap_key,
-        jnp.take_along_axis(key_all, src_safe[..., None], axis=1))
-    cached_margin = jnp.where(
-        src < 0, snap_margin,
-        jnp.take_along_axis(margin_all, src_safe, axis=1))
-    match = jnp.logical_and(
-        jnp.all(key_all == cached_key, axis=-1),
-        jnp.abs(margin_all - cached_margin) <= cfg.margin_eps)
-    reasoned = s_all * state.task_weights[:, None, :]
-    active = jnp.logical_and(is_write, jnp.logical_not(match))
+    with phase("reason"):
+        snap_out = jnp.take_along_axis(cache.out, idx[..., None], axis=1)
+        snap_key = jnp.take_along_axis(cache.topk_key, idx[..., None],
+                                       axis=1)
+        snap_margin = jnp.take_along_axis(cache.margin, idx, axis=1)
+        s_all = al.readout(acc_res, d_eff[:, None, None])    # [S, N, M]
+        vals, kidx = jax.lax.top_k(s_all.reshape(S * N, M), cfg.top_k)
+        # without these barriers XLA-CPU sees the sliced/reshaped consumers
+        # and re-lowers TopK as a full row sort — ~5x the whole pass at
+        # M = 1024. One barrier per output: a single barrier over the
+        # (vals, kidx) tuple crashes XLA-CPU's TopK decomposer inside a
+        # shard_map.
+        vals = jax.lax.optimization_barrier(vals)
+        kidx = jax.lax.optimization_barrier(kidx)
+        key_all = kidx.astype(jnp.int32).reshape(S, N, cfg.top_k)
+        margin_all = (vals[:, 0] - vals[:, 1]).reshape(S, N)
+        cached_key = jnp.where(
+            src[..., None] < 0, snap_key,
+            jnp.take_along_axis(key_all, src_safe[..., None], axis=1))
+        cached_margin = jnp.where(
+            src < 0, snap_margin,
+            jnp.take_along_axis(margin_all, src_safe, axis=1))
+        match = jnp.logical_and(
+            jnp.all(key_all == cached_key, axis=-1),
+            jnp.abs(margin_all - cached_margin) <= cfg.margin_eps)
+        reasoned = s_all * state.task_weights[:, None, :]
+        active = jnp.logical_and(is_write, jnp.logical_not(match))
 
-    def out_body(out_res, i):
-        read = jnp.where(src[:, i, None] < 0, snap_out[:, i],
-                         out_res[s_ix, src_safe[:, i]])
-        out_w = jnp.where(match[:, i, None], read, reasoned[:, i])
-        emit = jnp.where(is_pad[:, i, None], 0.0,
-                         jnp.where(is_byp[:, i, None], read, out_w))
-        return out_res.at[:, i].set(out_w), emit
+        def out_body(out_res, i):
+            read = jnp.where(src[:, i, None] < 0, snap_out[:, i],
+                             out_res[s_ix, src_safe[:, i]])
+            out_w = jnp.where(match[:, i, None], read, reasoned[:, i])
+            emit = jnp.where(is_pad[:, i, None], 0.0,
+                             jnp.where(is_byp[:, i, None], read, out_w))
+            return out_res.at[:, i].set(out_w), emit
 
-    out_res, outs = jax.lax.scan(out_body, jnp.zeros((S, N, M), jnp.float32),
-                                 jnp.arange(N))
-    outs = jnp.moveaxis(outs, 0, 1)                          # [S, N, M]
+        out_res, outs = jax.lax.scan(
+            out_body, jnp.zeros((S, N, M), jnp.float32), jnp.arange(N))
+        outs = jnp.moveaxis(outs, 0, 1)                      # [S, N, M]
 
-    written = writer_f >= 0                                  # [S, K]
-    wsafe = jnp.maximum(writer_f, 0)
-    w2 = written[..., None]
+    with phase("cache_write"):
+        written = writer_f >= 0                              # [S, K]
+        wsafe = jnp.maximum(writer_f, 0)
+        w2 = written[..., None]
 
-    def last_write(arr_prop, arr_snap):
-        return jnp.where(
-            w2, jnp.take_along_axis(arr_prop, wsafe[..., None], axis=1),
-            arr_snap)
+        def last_write(arr_prop, arr_snap):
+            return jnp.where(
+                w2, jnp.take_along_axis(arr_prop, wsafe[..., None], axis=1),
+                arr_snap)
 
-    cache = CacheState(
-        packed=last_write(q_packed_all, cache.packed),
-        acc=last_write(acc_res, cache.acc),
-        acc_tag=jnp.where(written, tag[:, None], cache.acc_tag),
-        out=last_write(out_res, cache.out),
-        topk_key=last_write(key_all, cache.topk_key),
-        margin=jnp.where(written,
-                         jnp.take_along_axis(margin_all, wsafe, axis=1),
-                         cache.margin),
-        age=age_f,
-        valid=valid_f,
-    )
+        cache = CacheState(
+            packed=last_write(q_packed_all, cache.packed),
+            acc=last_write(acc_res, cache.acc),
+            acc_tag=jnp.where(written, tag[:, None], cache.acc_tag),
+            out=last_write(out_res, cache.out),
+            topk_key=last_write(key_all, cache.topk_key),
+            margin=jnp.where(written,
+                             jnp.take_along_axis(margin_all, wsafe, axis=1),
+                             cache.margin),
+            age=age_f,
+            valid=valid_f,
+        )
     telem = (eff, d_count, rho, active)
     return jax.vmap(
         _finish_window,
@@ -346,17 +372,22 @@ def _decide_body(cfg: TorrConfig, banks, planes, wmask, high):
 
     def body(meta: query_cache.MetaCache, inp):
         q_packed, valid = inp
-        idx, rho, _ham = query_cache.nearest(meta, q_packed, cfg, banks,
-                                             planes)
-        d_idx, d_weight, d_count = al.delta_indices(
-            q_packed, meta.packed[idx], wmask, cfg.delta_budget, cfg.D
-        )
-        tag_ok = meta.acc_tag[idx] == tag
-        action = policy.select_path(rho, d_count, tag_ok, high, cfg)
-        eff = jnp.where(valid, action, jnp.int32(3))
-        # the LRU choice the apply pass's full branch will make — computed
-        # here because both passes see identical age/validity sequences
-        lru = query_cache.lru_slot(meta)
+        with phase("nearest"):
+            idx, rho, _ham = query_cache.nearest(meta, q_packed, cfg, banks,
+                                                 planes)
+        with phase("delta_search"):
+            d_idx, d_weight, d_count = al.delta_indices(
+                q_packed, meta.packed[idx], wmask, cfg.delta_budget, cfg.D
+            )
+        with phase("path_select"):
+            tag_ok = meta.acc_tag[idx] == tag
+            action = policy.select_path(rho, d_count, tag_ok, high, cfg)
+            eff = jnp.where(valid, action, jnp.int32(3))
+        with phase("cache_write"):
+            # the LRU choice the apply pass's full branch will make —
+            # computed here because both passes see identical age/validity
+            # sequences
+            lru = query_cache.lru_slot(meta)
 
         def bypass_branch(meta):
             return query_cache.meta_touch(meta, idx)
@@ -372,9 +403,10 @@ def _decide_body(cfg: TorrConfig, banks, planes, wmask, high):
         def pad_branch(meta):
             return meta
 
-        meta = jax.lax.switch(
-            eff, [bypass_branch, delta_branch, full_branch, pad_branch], meta
-        )
+        with phase("cache_write"):
+            meta = jax.lax.switch(
+                eff, [bypass_branch, delta_branch, full_branch, pad_branch],
+                meta)
         dec = (eff, idx, lru, d_idx, d_weight,
                jnp.where(valid, d_count, 0), jnp.where(valid, rho, 0.0))
         return meta, dec
@@ -444,10 +476,11 @@ def _decide_pass_batched_aux(cache: CacheState, q_packed_all, valid,
     wmask = plan_word_mask(cfg, banks, planes)
     tag = plan_tag(banks, planes)
     meta = query_cache.meta_view(cache)
-    ham_snap = query_cache.hamming_all(meta, q_packed_all, cfg, banks,
-                                       planes)                    # [N, K]
-    ham_prop = al.lookup_hamming_all(q_packed_all, q_packed_all,
-                                     wmask)                       # [N, N]
+    with phase("nearest"):
+        ham_snap = query_cache.hamming_all(meta, q_packed_all, cfg, banks,
+                                           planes)                # [N, K]
+        ham_prop = al.lookup_hamming_all(q_packed_all, q_packed_all,
+                                         wmask)                   # [N, N]
     d_eff = jnp.asarray(
         cfg.d_eff_planned(jnp.asarray(banks, jnp.int32), planes), jnp.float32)
     snap_tag_ok = meta.acc_tag == tag                             # [K]
@@ -457,28 +490,33 @@ def _decide_pass_batched_aux(cache: CacheState, q_packed_all, valid,
         writer, age, valid_k = carry
         hs, hp, v, i = inp
         live = writer >= 0
-        ham_k = jnp.where(live, hp[jnp.maximum(writer, 0)], hs)   # [K]
-        rho_k = 1.0 - 2.0 * ham_k.astype(jnp.float32) / d_eff     # Eq. 5
-        rho_k = jnp.where(valid_k, rho_k, -jnp.inf)
-        idx = jnp.argmax(rho_k).astype(jnp.int32)
-        rho = rho_k[idx]
-        d_count = ham_k[idx]
-        src = writer[idx]
-        tag_ok = jnp.where(live[idx], True, snap_tag_ok[idx])
-        action = policy.select_path(rho, d_count, tag_ok, high, cfg)
-        eff = jnp.where(v, action, jnp.int32(3))
-        lru = jnp.argmax(jnp.where(valid_k, age, int_max)).astype(jnp.int32)
-
-        # replay the meta_touch / meta_write metadata updates
-        is_pad = eff == jnp.int32(3)
-        is_write = jnp.logical_or(eff == jnp.int32(1), eff == jnp.int32(2))
-        slot = jnp.where(eff == jnp.int32(2), lru, idx)
-        bump = jnp.logical_not(is_pad)
-        age = age + bump.astype(jnp.int32)
-        age = age.at[slot].set(jnp.where(bump, 0, age[slot]))
-        writer = writer.at[slot].set(jnp.where(is_write, i, writer[slot]))
-        valid_k = valid_k.at[slot].set(
-            jnp.logical_or(valid_k[slot], is_write))
+        with phase("nearest"):
+            ham_k = jnp.where(live, hp[jnp.maximum(writer, 0)], hs)  # [K]
+            rho_k = 1.0 - 2.0 * ham_k.astype(jnp.float32) / d_eff   # Eq. 5
+            rho_k = jnp.where(valid_k, rho_k, -jnp.inf)
+            idx = jnp.argmax(rho_k).astype(jnp.int32)
+            rho = rho_k[idx]
+            d_count = ham_k[idx]
+            src = writer[idx]
+        with phase("path_select"):
+            tag_ok = jnp.where(live[idx], True, snap_tag_ok[idx])
+            action = policy.select_path(rho, d_count, tag_ok, high, cfg)
+            eff = jnp.where(v, action, jnp.int32(3))
+        with phase("cache_write"):
+            lru = jnp.argmax(jnp.where(valid_k, age, int_max)).astype(
+                jnp.int32)
+            # replay the meta_touch / meta_write metadata updates
+            is_pad = eff == jnp.int32(3)
+            is_write = jnp.logical_or(eff == jnp.int32(1),
+                                      eff == jnp.int32(2))
+            slot = jnp.where(eff == jnp.int32(2), lru, idx)
+            bump = jnp.logical_not(is_pad)
+            age = age + bump.astype(jnp.int32)
+            age = age.at[slot].set(jnp.where(bump, 0, age[slot]))
+            writer = writer.at[slot].set(jnp.where(is_write, i,
+                                                   writer[slot]))
+            valid_k = valid_k.at[slot].set(
+                jnp.logical_or(valid_k[slot], is_write))
         out = (eff, idx, lru, jnp.where(v, d_count, 0),
                jnp.where(v, rho, 0.0), src)
         return (writer, age, valid_k), out
@@ -490,11 +528,12 @@ def _decide_pass_batched_aux(cache: CacheState, q_packed_all, valid,
         (ham_snap, ham_prop, valid, arange))
 
     # one vmapped delta-index pass against the resolved old entries
-    old_packed = jnp.where(src[:, None] < 0, cache.packed[idx],
-                           q_packed_all[jnp.maximum(src, 0)])
-    d_idx, d_weight, _cnt = jax.vmap(
-        lambda qn, qo: al.delta_indices(qn, qo, wmask, cfg.delta_budget,
-                                        cfg.D))(q_packed_all, old_packed)
+    with phase("delta_search"):
+        old_packed = jnp.where(src[:, None] < 0, cache.packed[idx],
+                               q_packed_all[jnp.maximum(src, 0)])
+        d_idx, d_weight, _cnt = jax.vmap(
+            lambda qn, qo: al.delta_indices(qn, qo, wmask, cfg.delta_budget,
+                                            cfg.D))(q_packed_all, old_packed)
     dec = (eff, idx, lru, d_idx, d_weight, d_count, rho)
     return dec, (src,) + carry_f
 
@@ -624,12 +663,13 @@ def torr_window_step(
     if fused not in _FUSED_MODES:
         raise ValueError(f"fused={fused!r} not in {_FUSED_MODES}")
     planes, cap, cfg = _plan_static(plan, cfg)
-    n_valid = jnp.sum(valid.astype(jnp.int32))
-    high = policy.high_load(n_valid, queue_depth, cfg)
-    banks = policy.select_banks(n_valid, queue_depth, cfg)
-    if plan is not None and plan.banks < cfg.B:
-        banks = jnp.minimum(banks, jnp.int32(plan.banks))
-    wmask = plan_word_mask(cfg, banks, planes)
+    with phase("policy"):
+        n_valid = jnp.sum(valid.astype(jnp.int32))
+        high = policy.high_load(n_valid, queue_depth, cfg)
+        banks = policy.select_banks(n_valid, queue_depth, cfg)
+        if plan is not None and plan.banks < cfg.B:
+            banks = jnp.minimum(banks, jnp.int32(plan.banks))
+        wmask = plan_word_mask(cfg, banks, planes)
     arange = jnp.arange(cfg.N_max, dtype=jnp.int32)
 
     decide_id, btier = DECIDE_NONE, 0
@@ -641,10 +681,11 @@ def torr_window_step(
                      else _decide_pass)
         dec = decide_fn(state.cache, q_packed_all, valid, cfg, banks,
                         planes, high)
-        acc_rows = al.compact_full_scores(
-            q_packed_all, dec[0] == PATH_FULL,
-            jnp.broadcast_to(banks, (cfg.N_max,)), im, cfg, planes=planes,
-            cap=cap, bucket_cap=btier)
+        with phase("full_select"):
+            acc_rows = al.compact_full_scores(
+                q_packed_all, dec[0] == PATH_FULL,
+                jnp.broadcast_to(banks, (cfg.N_max,)), im, cfg,
+                planes=planes, cap=cap, bucket_cap=btier)
         body = _proposal_body(cfg, im, state.task_weights, banks, planes,
                               wmask, high, acc_full_all=acc_rows,
                               fused_delta=True, decided=True)
@@ -653,9 +694,10 @@ def torr_window_step(
     else:
         acc_full_all = None
         if fused != "off":
-            acc_full_all = al.full_scores_all(
-                q_packed_all, im, banks, cfg, planes=planes, cap=cap,
-                mode=fused, ham_prefix=ham_prefix_all)
+            with phase("full_select"):
+                acc_full_all = al.full_scores_all(
+                    q_packed_all, im, banks, cfg, planes=planes, cap=cap,
+                    mode=fused, ham_prefix=ham_prefix_all)
 
         # The scalar-prefetch delta kernel pays off where branch economy is
         # real (the "switch" lowering: only the selected path executes).
@@ -684,29 +726,30 @@ def _finish_window(cache, task_w, outs, telem, valid, boxes, queue_depth,
     the *static* resolved lowering knobs (``types.FUSED_IDS`` /
     ``types.DECIDE_IDS`` encodings) the dispatching step records into the
     trace."""
-    actions, d_counts, rhos, active = telem
-    # padding actions (3) are reported as bypass with zero cost
-    path = jnp.where(actions == 3, PATH_BYPASS, actions)
-    telemetry = WindowTelemetry(
-        path=path.astype(jnp.int32),
-        delta_count=d_counts.astype(jnp.int32),
-        banks=banks,
-        rho=rhos.astype(jnp.float32),
-        n_valid=n_valid,
-        reasoner_active=jnp.logical_and(active, valid),
-        queue_depth=jnp.asarray(queue_depth, jnp.int32),
-        high_load=high,
-        planes=jnp.int32(planes),
-        fused_mode=jnp.int32(fused_mode),
-        decide_mode=jnp.int32(decide_mode),
-        bucket_tier=jnp.int32(bucket_tier),
-    )
-    out = WindowOutput(
-        scores=outs,
-        best=jnp.argmax(outs, axis=-1).astype(jnp.int32),
-        boxes=boxes,
-    )
-    return TorrState(cache=cache, task_weights=task_w), out, telemetry
+    with phase("finish"):
+        actions, d_counts, rhos, active = telem
+        # padding actions (3) are reported as bypass with zero cost
+        path = jnp.where(actions == 3, PATH_BYPASS, actions)
+        telemetry = WindowTelemetry(
+            path=path.astype(jnp.int32),
+            delta_count=d_counts.astype(jnp.int32),
+            banks=banks,
+            rho=rhos.astype(jnp.float32),
+            n_valid=n_valid,
+            reasoner_active=jnp.logical_and(active, valid),
+            queue_depth=jnp.asarray(queue_depth, jnp.int32),
+            high_load=high,
+            planes=jnp.int32(planes),
+            fused_mode=jnp.int32(fused_mode),
+            decide_mode=jnp.int32(decide_mode),
+            bucket_tier=jnp.int32(bucket_tier),
+        )
+        out = WindowOutput(
+            scores=outs,
+            best=jnp.argmax(outs, axis=-1).astype(jnp.int32),
+            boxes=boxes,
+        )
+        return TorrState(cache=cache, task_weights=task_w), out, telemetry
 
 
 # ---------------------------------------------------------------------------
@@ -847,12 +890,13 @@ def _multi_stream_compact_step(
     S, N, W = q_packed_all.shape
     bcap = _resolve_bucket_cap(bucket_cap, plan, S * N)
 
-    n_valid = jnp.sum(valid.astype(jnp.int32), axis=-1)        # [S]
-    high = policy.high_load(n_valid, queue_depth, cfg)          # [S]
-    banks = jax.vmap(lambda n, qd: policy.select_banks(n, qd, cfg))(
-        n_valid, queue_depth)                                   # [S]
-    if plan is not None and plan.banks < cfg.B:
-        banks = jnp.minimum(banks, jnp.int32(plan.banks))
+    with phase("policy"):
+        n_valid = jnp.sum(valid.astype(jnp.int32), axis=-1)    # [S]
+        high = policy.high_load(n_valid, queue_depth, cfg)      # [S]
+        banks = jax.vmap(lambda n, qd: policy.select_banks(n, qd, cfg))(
+            n_valid, queue_depth)                               # [S]
+        if plan is not None and plan.banks < cfg.B:
+            banks = jnp.minimum(banks, jnp.int32(plan.banks))
 
     decide_mode = _resolve_decide(decide)
     if decide_mode == "batched":
@@ -866,12 +910,13 @@ def _multi_stream_compact_step(
         )(state.cache, q_packed_all, valid, banks, high)
         aux = None
 
-    acc_rows = al.compact_full_scores(
-        q_packed_all.reshape(S * N, W),
-        (dec[0] == PATH_FULL).reshape(S * N),
-        jnp.broadcast_to(banks[:, None], (S, N)).reshape(S * N),
-        im, cfg, planes=planes, cap=cap, bucket_cap=bcap,
-    ).reshape(S, N, cfg.M)
+    with phase("full_select"):
+        acc_rows = al.compact_full_scores(
+            q_packed_all.reshape(S * N, W),
+            (dec[0] == PATH_FULL).reshape(S * N),
+            jnp.broadcast_to(banks[:, None], (S, N)).reshape(S * N),
+            im, cfg, planes=planes, cap=cap, bucket_cap=bcap,
+        ).reshape(S, N, cfg.M)
 
     # The batched decide's conflict byproducts unlock the batched apply
     # (value math hoisted dispatch-wide); ``decide="scan"`` pins the
@@ -887,7 +932,8 @@ def _multi_stream_compact_step(
 
     def apply_one(args):
         st, q, v, b, qd, bk, h, nv, dec_s, accs = args
-        wmask = plan_word_mask(cfg, bk, planes)
+        with phase("policy"):
+            wmask = plan_word_mask(cfg, bk, planes)
         body = _proposal_body(cfg, im, st.task_weights, bk, planes,
                               wmask, h, acc_full_all=accs,
                               fused_delta=True, decided=True)

@@ -73,6 +73,7 @@ def delta_update(
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
+        name="delta_update",
         out_shape=jax.ShapeDtypeStruct((1, M), jnp.int32),
         interpret=resolve_interpret(interpret),
     )(idx, weight, acc.reshape(1, M), dmajor)

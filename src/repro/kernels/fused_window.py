@@ -161,6 +161,7 @@ def fused_scores(
     acc, best, top2 = pl.pallas_call(
         kern,
         grid=(N // tq, M // tm, nw),
+        name="fused_scores",
         in_specs=[
             pl.BlockSpec((tq, tw), lambda n, m, w: (n, w)),
             pl.BlockSpec((tm, tw), lambda n, m, w: (m, w)),
@@ -289,6 +290,7 @@ def bank_prefix_hamming(
     return pl.pallas_call(
         kern,
         grid=(N // tq, M // tm),
+        name="bank_prefix_hamming",
         in_specs=[
             pl.BlockSpec((tq, W + pad), lambda n, m: (n, 0)),
             pl.BlockSpec((tm, W + pad), lambda n, m: (m, 0)),
@@ -408,6 +410,7 @@ def sign_project_pack(
     words_t = pl.pallas_call(
         _pack_kernel,
         grid=(D // td, N // tn),
+        name="sign_project_pack",
         in_specs=[
             pl.BlockSpec((td, d), lambda dd, n: (dd, 0)),
             pl.BlockSpec((tn, d), lambda dd, n: (n, 0)),

@@ -48,6 +48,7 @@ def sign_project(
     return pl.pallas_call(
         _kernel,
         grid=(N // tn, D // td),
+        name="sign_project",
         in_specs=[
             pl.BlockSpec((tn, d), lambda n, dd: (n, 0)),
             pl.BlockSpec((td, d), lambda n, dd: (dd, 0)),

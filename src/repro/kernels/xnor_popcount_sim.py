@@ -189,6 +189,7 @@ def packed_hamming_batched(
     return pl.pallas_call(
         _kernel,
         grid=grid,
+        name="packed_hamming_batched",
         in_specs=[
             pl.BlockSpec((tq, tw), lambda n, m, w: (n, w)),
             pl.BlockSpec((tm, tw), lambda n, m, w: (m, w)),

@@ -11,9 +11,10 @@ collector, and the benchmark overhead gate. Either pillar may be absent:
 Per-step protocol (both engines):
 
 1. ``rec = obs.on_dispatch(...)`` right after the jitted step launches —
-   counts the step/windows/pad lanes and opens a flight record carrying
-   the requested lowering, the latched plan, and the governor's state at
-   dispatch time (``None`` without a flight recorder).
+   counts the step/windows/pad lanes, observes each served window's wait
+   in the engine's queue (dispatch time minus arrival), and opens a flight
+   record carrying the requested lowering, the latched plan, and the
+   governor's state at dispatch time (``None`` without a flight recorder).
 2. ``obs.observe_step(tel_host, rec, step_latency_s)`` once the step's
    telemetry is host-resident (the sync engine's deferred fold; the async
    collector) — reduces the [S]-batched trace to a digest, feeds the
@@ -22,6 +23,9 @@ Per-step protocol (both engines):
    (collector drain on worker death, futures cancelled mid-flight) —
    the ``torr_telemetry_dropped_total`` counter is the audit trail for
    the silent-loss bug class this subsystem closes.
+
+Once, at warm-up, ``obs.publish_phases(table)`` publishes the served
+step's phase table (:func:`repro.obs.phases.phase_table`).
 
 The digest's key names deliberately match ``perf.cycle_model``'s
 vocabulary (``path`` names from ``core.types.PATH_NAMES``, ``banks``/
@@ -136,6 +140,11 @@ class StepObserver:
             "torr_step_latency_seconds",
             "Dispatch to results-ready latency of one batched step.",
             buckets=LATENCY_BUCKETS_S)
+        self._h_queue = r.histogram(
+            "torr_window_queue_seconds",
+            "Wait of a served window in the engine's queue: from submit "
+            "to the dispatch of the step that takes it.",
+            buckets=LATENCY_BUCKETS_S)
         self._g_ewma = r.gauge(
             "torr_full_path_ewma",
             "Auto-dispatch full-path-fraction EWMA (compact tier input).")
@@ -164,19 +173,23 @@ class StepObserver:
     # -- per-step protocol ---------------------------------------------------
 
     def on_dispatch(self, n_served: int, n_pad: int, requested=None,
-                    plan=None, gov=None, full_ewma=None) -> Optional[dict]:
+                    plan=None, gov=None, full_ewma=None,
+                    waits=()) -> Optional[dict]:
         """Record one launched step; returns the open flight record.
 
         ``requested`` is the ``(fused, bucket_cap, decide)`` static args
         the host dispatched with (the resolved lowering lands from the
         telemetry in :meth:`observe_step`); ``plan`` the latched
         ``KnobPlan`` (or None); ``gov`` a dict of the governor's state at
-        dispatch (``level``/``slack``/``energy_ewma_mj``).
+        dispatch (``level``/``slack``/``energy_ewma_mj``); ``waits`` each
+        served window's seconds in the engine's queue.
         """
         if self._c_steps is not None:
             self._c_steps.inc()
             self._c_windows.inc(n_served)
             self._c_pad.inc(n_pad)
+            for w in waits:
+                self._h_queue.observe(w)
             if full_ewma is not None:
                 self._g_ewma.set(full_ewma)
         if self.flight is None:
@@ -192,6 +205,23 @@ class StepObserver:
         if gov is not None:
             fields["governor"] = gov
         return self.flight.record(**fields)
+
+    def publish_phases(self, table: dict) -> None:
+        """Publish the served step's phase table (``{instruction: phase}``)
+        as ``torr_step_phase_ops{phase, ops}``: one series per phase, its
+        ``ops`` label the space-separated instruction names, its value
+        their count."""
+        if self._c_steps is None:
+            return
+        by_phase = {}
+        for op, ph in table.items():
+            by_phase.setdefault(ph, []).append(op)
+        g = self.registry.gauge(
+            "torr_step_phase_ops",
+            "Ops of the served step's executable by named phase.",
+            ["phase", "ops"])
+        for ph, ops in sorted(by_phase.items()):
+            g.labels(phase=ph, ops=" ".join(sorted(ops))).set(len(ops))
 
     def observe_step(self, tel_h, rec: Optional[dict] = None,
                      step_latency_s: Optional[float] = None) -> dict:

@@ -150,6 +150,7 @@ class AsyncStreamEngine(StreamEngine):
             if metrics is not None or tracer is not None \
             else (lambda name: NULL_SPAN)
         self._sp_decide = sp("host_decide")
+        self._sp_wait = sp("dispatch_wait")
         self._sp_device = sp("device_step")
         self._sp_drain = sp("collector_drain")
         self._last_slack = None
@@ -344,11 +345,6 @@ class AsyncStreamEngine(StreamEngine):
 
     # -- dispatcher ---------------------------------------------------------
 
-    @staticmethod
-    def _ctx_of(extra):
-        # submit's trailing payload here is (future, arrival, ctx)
-        return extra[2]
-
     def _has_backlog(self) -> bool:
         return any(self._pending[s] for s in self._slot_of.values())
 
@@ -423,35 +419,22 @@ class AsyncStreamEngine(StreamEngine):
         # _observe_path_mix (and the observer) from there
         pass
 
-    def _dispatch(self, q, v, b, qd):
+    def _batch(self, q, v, b, qd):
         if self._mesh is None:
-            return super()._dispatch(q, v, b, qd)
+            return super()._batch(q, v, b, qd)
         from ..core.types import StreamBatch
         s = self._batch_sharding
-        batch = StreamBatch(
+        return StreamBatch(
             q_packed=jax.device_put(q, s), valid=jax.device_put(v, s),
             boxes=jax.device_put(b, s),
             queue_depth=jax.device_put(qd.astype(np.int32), s),
         )
-        fused, bucket_cap, decide = self._resolve_fused()
-        self._last_resolved = (fused, bucket_cap, decide)
-        self._state, out, tel = self._step(
-            self._state, self.im, batch, self.cfg, serial=self._serial,
-            plan=self._plan, fused=fused, bucket_cap=bucket_cap,
-            decide=decide)
-        return out, tel
 
     def warmup(self) -> None:
         """Compile the batched step (with its sharded layout when meshed)
         outside any timed region: one all-pad step, a state no-op."""
         with self._lock:
-            S = self.n_slots
-            out, _tel = self._dispatch(
-                np.broadcast_to(self._q0, (S,) + self._q0.shape),
-                np.broadcast_to(self._v0, (S,) + self._v0.shape),
-                np.broadcast_to(self._b0, (S,) + self._b0.shape),
-                np.zeros((S,), np.int32))
-            jax.block_until_ready(out.scores)
+            super().warmup()
 
     def _dispatch_loop(self) -> None:
         deferred = []   # (future, exception) of windows shed under the lock
@@ -460,8 +443,11 @@ class AsyncStreamEngine(StreamEngine):
                 deferred = []
                 step_ctxs = None
                 with self._work:
-                    while not self._stop and not self._has_backlog():
-                        self._work.wait()
+                    if not self._stop and not self._has_backlog():
+                        with self._sp_wait:
+                            while not self._stop and \
+                                    not self._has_backlog():
+                                self._work.wait()
                     if self._stop:
                         break
                     if self._fault is not None:
@@ -513,12 +499,15 @@ class AsyncStreamEngine(StreamEngine):
                                     "energy_ewma_mj":
                                         self._governor.energy_ewma_mj,
                                 }
+                            t_queue = (self._tracker.now() if self._tracker
+                                       else t0)
                             rec = self._obs.on_dispatch(
                                 len(served), self.n_slots - len(served),
                                 requested=self._last_resolved,
                                 plan=self._plan, gov=gov,
                                 full_ewma=(self._full_ewma if self._auto
-                                           else None))
+                                           else None),
+                                waits=self._queue_waits(served, t_queue))
                             if rec is not None and self._tracer is not None:
                                 rec["ts_us"] = now_us()
                                 rec["queue_depth"] = int(qd.max())

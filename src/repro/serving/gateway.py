@@ -52,7 +52,9 @@ Design notes
 Metrics land in the shared :class:`repro.obs.metrics.MetricsRegistry`
 (``torr_gateway_*`` — catalog in docs/observability.md); they reconcile
 exactly against a well-behaved client's own counts, which
-``benchmarks/loadgen.py`` asserts.
+``benchmarks/loadgen.py`` asserts. A window's decode and its result's
+encode are the spans ``gateway_decode`` and ``gateway_encode``
+(:mod:`repro.obs.spans`), so they also show in a profile of the process.
 """
 from __future__ import annotations
 
@@ -69,6 +71,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from ..obs.spans import span
 from ..runtime.fault import EngineDead
 from .deadline import WindowShed
 from . import protocol
@@ -327,6 +330,14 @@ class Gateway:
                 "torr_telemetry_dropped_total", _DROPPED_HELP)
         else:
             self._m_dropped = None
+        # the window route's host spans (decorators: thread-safe across
+        # the request threads); protocol's functions are looked up per call
+        self._decode_window = span("gateway_decode", metrics)(
+            lambda body: protocol.validate_window(
+                protocol.parse_json_body(body), self._cfg))
+        self._encode_result = span("gateway_encode", metrics)(
+            lambda seq, wout: json.dumps(
+                protocol.window_result_body(seq, wout)).encode())
 
         self._lsock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
@@ -844,8 +855,7 @@ class Gateway:
     def _h_window(self, conn, method, path, body, keep) -> None:
         if method != "POST":
             raise ProtocolError(405, "bad_request", "POST only")
-        wr = protocol.validate_window(protocol.parse_json_body(body),
-                                      self._cfg)
+        wr = self._decode_window(body)
         with self._glock:
             if self._draining:
                 raise ProtocolError(503, "draining", "gateway is draining")
@@ -956,16 +966,14 @@ class Gateway:
                     self._m_disc.inc()
                     self._m_rej.labels(reason="disconnect").inc()
                 raise _Disconnect()
-        body = json.dumps(
-            protocol.window_result_body(seq, wout)).encode()
+        body = self._encode_result(seq, wout)
         sess.cached_seq, sess.cached_body = seq, body
         self._count("window", 200, None)
         self._send(conn, 200, body, "application/json", keep)
 
     def _cache(self, sess: _Session, seq: int, wout) -> None:
         sess.cached_seq = seq
-        sess.cached_body = json.dumps(
-            protocol.window_result_body(seq, wout)).encode()
+        sess.cached_body = self._encode_result(seq, wout)
 
     def _park(self, sess: _Session, seq: int, fut: Future) -> None:
         sess.parked[seq] = fut

@@ -37,6 +37,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
+import time
 from typing import Dict
 
 import jax
@@ -48,6 +50,7 @@ from ..core.item_memory import ItemMemory
 from ..core.pipeline import TorrState, WindowOutput
 from ..core.types import PATH_FULL, StreamBatch, TorrConfig, WindowTelemetry
 from ..obs.bridge import StepObserver, telemetry_digest
+from ..obs.phases import phase_table
 from ..obs.spans import NULL_SPAN, span
 from ..obs.trace import now_us, trace_scope
 
@@ -258,23 +261,18 @@ class StreamEngine:
     def submit(self, stream_id, q_packed, valid, boxes) -> None:
         """Enqueue one window (packed queries, validity, boxes) for a stream.
 
-        With a tracer armed, a per-window :class:`TraceContext` is minted
-        here (this is the window's admission timestamp) and rides the
-        pending tuple as the trailing payload."""
+        The window's trailing payload is ``(future, arrival, ctx)``, as in
+        the async engine: no future here, the arrival time on the
+        monotonic clock, and with a tracer armed a per-window
+        :class:`TraceContext` minted here (the window's admission
+        timestamp), else None."""
         slot = self._slot_of[stream_id]
-        window = (np.asarray(q_packed, np.uint32),
-                  np.asarray(valid, bool),
-                  np.asarray(boxes, np.float32))
-        if self._tracer is not None:
-            window += (self._tracer.mint(stream_id, self._ENGINE),)
-        self._pending[slot].append(window)
-
-    @staticmethod
-    def _ctx_of(extra):
-        """The window's TraceContext from ``submit``'s trailing payload
-        (None when untraced). The async engine overrides — its payload
-        carries (future, arrival, ctx)."""
-        return extra[0] if extra else None
+        ctx = (self._tracer.mint(stream_id, self._ENGINE)
+               if self._tracer is not None else None)
+        self._pending[slot].append((np.asarray(q_packed, np.uint32),
+                                    np.asarray(valid, bool),
+                                    np.asarray(boxes, np.float32),
+                                    None, time.monotonic(), ctx))
 
     def backlog(self, stream_id) -> int:
         return len(self._pending[self._slot_of[stream_id]])
@@ -287,11 +285,11 @@ class StreamEngine:
         """Pop the head window of every busy slot into padded host buffers.
 
         Returns ``(q, v, b, qd, served)`` where served is a list of
-        ``(stream_id, slot, extra)`` — ``extra`` is whatever trailing payload
-        ``submit`` queued alongside the window arrays (the async engine
-        rides its per-window future and arrival time here). Idle slots stay
-        all-pad; ``qd`` is each served slot's *remaining* backlog after the
-        pop, so Alg. 1's load gate sees true per-stream pressure.
+        ``(stream_id, slot, extra)`` — ``extra`` is the ``(future, arrival,
+        ctx)`` payload ``submit`` queued alongside the window arrays. Idle
+        slots stay all-pad; ``qd`` is each served slot's *remaining*
+        backlog after the pop, so Alg. 1's load gate sees true per-stream
+        pressure.
 
         ``gate(stream_id, backlog_after_pop, extra) -> GATE_*`` is the
         optional admission hook (the async engine's RT-deadline controller):
@@ -320,7 +318,7 @@ class StreamEngine:
                 if decision == GATE_ESCALATE:
                     qd[slot] = max(qd[slot], self.cfg.q_hi)
                 served.append((stream_id, slot, extra))
-                ctx = self._ctx_of(extra)
+                ctx = extra[2]
                 if ctx is not None:
                     ctx.slot = slot
                     if ctx.decision is None:  # a gate may have stamped it
@@ -492,20 +490,33 @@ class StreamEngine:
         """The auto dispatcher's current full-path-fraction estimate."""
         return self._full_ewma
 
-    def _dispatch(self, q, v, b, qd):
-        """Launch one batched step (asynchronously) and advance the state."""
-        batch = StreamBatch(
+    def _batch(self, q, v, b, qd) -> StreamBatch:
+        """One step's host buffers as the step's device batch."""
+        return StreamBatch(
             q_packed=jnp.asarray(q), valid=jnp.asarray(v),
             boxes=jnp.asarray(b), queue_depth=jnp.asarray(qd),
         )
-        fused, bucket_cap, decide = self._resolve_fused()
-        self._last_resolved = (fused, bucket_cap, decide)
-        self._state, out, tel = self._step(
-            self._state, self.im, batch, self.cfg, serial=self._serial,
-            plan=self._plan, fused=fused, bucket_cap=bucket_cap,
-            decide=decide,
-        )
+
+    def _step_call(self, batch, resolved):
+        """``(args, kwargs)`` of the step over ``batch`` with the resolved
+        ``(fused, bucket_cap, decide)``."""
+        fused, bucket_cap, decide = resolved
+        return ((self._state, self.im, batch, self.cfg),
+                dict(serial=self._serial, plan=self._plan, fused=fused,
+                     bucket_cap=bucket_cap, decide=decide))
+
+    def _dispatch(self, q, v, b, qd):
+        """Launch one batched step (asynchronously) and advance the state."""
+        batch = self._batch(q, v, b, qd)
+        self._last_resolved = self._resolve_fused()
+        args, kw = self._step_call(batch, self._last_resolved)
+        self._state, out, tel = self._step(*args, **kw)
         return out, tel
+
+    @staticmethod
+    def _queue_waits(served, now):
+        """Each served window's seconds in the queue at dispatch time."""
+        return [now - extra[1] for _sid, _slot, extra in served]
 
     def step(self) -> Dict[object, tuple[WindowOutput, WindowTelemetry]]:
         """Drain one window per busy slot through the batched step."""
@@ -529,6 +540,7 @@ class StreamEngine:
                 if not served:  # idle engine: skip the no-op device step
                     return {}
                 with self._sp_dispatch:
+                    t_dispatch = time.monotonic()
                     out, tel = self._dispatch(q, v, b, qd)
         finally:
             self._step_ctxs = None
@@ -545,7 +557,8 @@ class StreamEngine:
                 rec = self._obs.on_dispatch(
                     len(served), self.n_slots - len(served),
                     requested=self._last_resolved, plan=self._plan,
-                    full_ewma=self._full_ewma if self._auto else None)
+                    full_ewma=self._full_ewma if self._auto else None,
+                    waits=self._queue_waits(served, t_dispatch))
                 if rec is not None and self._tracer is not None:
                     rec["ts_us"] = now_us()
                     rec["queue_depth"] = int(qd.max())
@@ -595,18 +608,34 @@ class StreamEngine:
         """Compile the batched step outside any timed region.
 
         Runs one all-pad step (a state no-op: every lane takes the pad
-        branch) and discards the result; stats are not touched."""
-        zero = StreamBatch(
-            q_packed=jnp.asarray(np.broadcast_to(
-                self._q0, (self.n_slots,) + self._q0.shape)),
-            valid=jnp.asarray(np.broadcast_to(
-                self._v0, (self.n_slots,) + self._v0.shape)),
-            boxes=jnp.asarray(np.broadcast_to(
-                self._b0, (self.n_slots,) + self._b0.shape)),
-            queue_depth=jnp.zeros((self.n_slots,), jnp.int32),
-        )
-        fused, bucket_cap, decide = self._resolve_fused()
-        out = self._step(self._state, self.im, zero, self.cfg,
-                         serial=self._serial, plan=self._plan,
-                         fused=fused, bucket_cap=bucket_cap, decide=decide)
+        branch) and discards the result; stats are not touched. With a
+        registry, then publishes the phase table of the executable that
+        step ran (:meth:`_publish_phases`)."""
+        S = self.n_slots
+        zero = self._batch(np.broadcast_to(self._q0, (S,) + self._q0.shape),
+                           np.broadcast_to(self._v0, (S,) + self._v0.shape),
+                           np.broadcast_to(self._b0, (S,) + self._b0.shape),
+                           np.zeros((S,), np.int32))
+        args, kw = self._step_call(zero, self._resolve_fused())
+        out = self._step(*args, **kw)
         jax.block_until_ready(out[1].scores)
+        self._publish_phases(args, kw)
+
+    def _publish_phases(self, args, kw) -> None:
+        """Publish the served step's phase table (``repro.obs.phases``):
+        the step lowered again with the arguments the warm-up ran, which
+        JAX's compile caches serve, so the table names the ops of the very
+        executable that serves. Only with a registry and a jitted step;
+        skipped where the backend keeps no HLO text."""
+        if self._obs is None or self._obs.registry is None:
+            return
+        step = self._step
+        if isinstance(step, functools.partial):   # the meshed step
+            step, kw = step.func, {**kw, **step.keywords}
+        if not hasattr(step, "lower"):             # jit=False
+            return
+        try:
+            text = step.lower(*args, **kw).compile().as_text()
+        except NotImplementedError:
+            return
+        self._obs.publish_phases(phase_table(text))

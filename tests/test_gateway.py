@@ -156,7 +156,7 @@ def _frame(seed=0, deadline_ms=None, session="t0/s0", seq=0):
 
 
 def test_config_health_and_session_roundtrip():
-    gw, _ = _gw()
+    gw, reg = _gw()
     try:
         st, _, cfg = _req(gw.port, "GET", "/v1/config")
         assert st == 200
@@ -192,6 +192,11 @@ def test_config_health_and_session_roundtrip():
         assert st == 200 and b["closed"] == "t0/s0"
         st, _, b = _req(gw.port, "POST", "/v1/window", _frame(seq=1))
         assert st == 404 and b["error"] == "no_session"
+        # every window request is decoded; only a fresh result is encoded
+        # (the retry replays the cached body)
+        spans = {s["labels"]["span"]: s["count"] for s in
+                 reg.snapshot()["torr_span_duration_seconds"]["series"]}
+        assert spans == {"gateway_decode": 4, "gateway_encode": 1}
     finally:
         gw.close()
 

@@ -11,7 +11,11 @@ async run whose flight-recorder plan timeline bit-matches the governor's
 own ``plan_log``. The cancelled-future path pins the telemetry-loss
 accounting (``telemetry_dropped``) the subsystem exists to close.
 """
+import glob
 import json
+import os
+import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -27,7 +31,7 @@ from repro.obs.export import (MetricsServer, health_response,
 from repro.obs.flight import (FLIGHT_SCHEMA_VERSION, FlightRecorder,
                               load_jsonl, plan_timeline, replay)
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.spans import NULL_SPAN, current_span, span, span_stack
+from repro.obs.spans import NULL_SPAN, span
 from repro.serving.async_engine import AsyncStreamEngine
 from repro.serving.deadline import DeadlinePolicy, DeadlineTracker
 from repro.serving.stream_engine import StreamEngine
@@ -320,29 +324,78 @@ def test_replay_skips_foreign_versions_and_sorts():
 # --- spans ------------------------------------------------------------------
 
 
-def test_span_nesting_and_histogram():
+def _profiled_events(log_dir, names):
+    """``[(name, start_ns, end_ns, thread line)]`` of the host events with
+    one of ``names`` in the profile written under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, line.name)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events if e.name in names]
+
+
+def test_span_nesting_and_histogram(tmp_path):
+    """Spans nest in the profiler's trace, and each exit observes its
+    histogram; NULL_SPAN records neither."""
     reg = MetricsRegistry()
-    assert current_span() is None
-    with span("outer", reg):
-        assert current_span() == "outer"
-        with span("inner", reg):
-            assert span_stack() == ("outer", "inner")
-        assert span_stack() == ("outer",)
-    assert span_stack() == ()
 
     @span("work", reg)
     def work():
-        return current_span()
+        time.sleep(0.001)
 
-    assert work() == "work"
-    work()
+    with jax.profiler.trace(str(tmp_path)):
+        with span("outer", reg):
+            with span("inner", reg):
+                time.sleep(0.001)
+            work()
+        with NULL_SPAN:
+            work()
+    ev = _profiled_events(str(tmp_path), {"outer", "inner", "work"})
+    by_name = {}
+    for name, t0, t1, _line in ev:
+        by_name.setdefault(name, []).append((t0, t1))
+    (outer,), (inner,) = by_name["outer"], by_name["inner"]
+    assert outer[0] <= inner[0] < inner[1] <= outer[1]
+    works = sorted(by_name["work"])
+    assert len(works) == 2
+    assert outer[0] <= works[0][0] < works[0][1] <= outer[1]
+    assert works[1][0] >= outer[1]          # the second call is outside
     snap = reg.snapshot()["torr_span_duration_seconds"]
     by_label = {s["labels"]["span"]: s for s in snap["series"]}
     assert by_label["outer"]["count"] == 1
     assert by_label["inner"]["count"] == 1
     assert by_label["work"]["count"] == 2
-    with NULL_SPAN:                                     # no stack, no metric
-        assert current_span() is None
+    assert by_label["outer"]["sum"] >= by_label["inner"]["sum"] >= 1e-3
+    assert set(by_label) == {"outer", "inner", "work"}  # NULL_SPAN: none
+
+
+def test_span_writes_its_name_into_a_cpu_profile(tmp_path):
+    """Both forms annotate the profile of the thread they run on, and with
+    no registry still do (the histogram is the only part they skip)."""
+    done = threading.Event()
+
+    @span("decorated_span")
+    def decorated():
+        time.sleep(0.001)
+
+    def worker():
+        decorated()
+        with span("managed_span"):
+            time.sleep(0.001)
+        done.set()
+
+    with jax.profiler.trace(str(tmp_path)):
+        t = threading.Thread(target=worker, name="span-worker")
+        t.start()
+        t.join()
+    assert done.is_set()
+    ev = _profiled_events(str(tmp_path), {"decorated_span", "managed_span"})
+    assert sorted(name for name, *_ in ev) == ["decorated_span",
+                                               "managed_span"]
+    assert all(t1 - t0 >= 1e6 for _n, t0, t1, _l in ev)   # >= the 1 ms sleep
+    assert len({line for *_x, line in ev}) == 1           # one thread
 
 
 # --- engine integration -----------------------------------------------------
@@ -464,3 +517,120 @@ def test_step_observer_digest_without_registry():
     assert rec is not None and rec["requested"]["fused"] == "switch"
     obs2 = StepObserver(registry=MetricsRegistry(), flight=None)
     assert obs2.on_dispatch(2, 0) is None               # no flight: no rec
+
+
+# --- queue wait, dispatcher wait, phase table -------------------------------
+
+
+def _spy_queue_waits(eng) -> list:
+    """Record each served window's ``(extra, wait)`` as the engine computes
+    them for ``torr_window_queue_seconds``."""
+    seen, orig = [], eng._queue_waits
+
+    def spy(served, now):
+        waits = orig(served, now)
+        seen.extend((extra, w) for (_sid, _slot, extra), w
+                    in zip(served, waits))
+        return waits
+    eng._queue_waits = spy
+    return seen
+
+
+def _queue_hist(reg):
+    (series,) = reg.snapshot()["torr_window_queue_seconds"]["series"]
+    return series
+
+
+def test_async_window_queue_seconds_once_per_window_within_its_latency():
+    cfg = CFG
+    S, T = 3, 4
+    im = random_item_memory(jax.random.PRNGKey(0), cfg)
+    task_w = np.asarray(jax.random.uniform(jax.random.PRNGKey(1), (S, cfg.M)))
+    steps = _make_inputs(cfg, S, T)
+    reg = MetricsRegistry()
+    sent, done = {}, {}
+    with AsyncStreamEngine(cfg, im, n_slots=S, paused=True,
+                           metrics=reg) as eng:
+        seen = _spy_queue_waits(eng)
+        for s in range(S):
+            eng.admit(f"cam{s}", task_w[s])
+        for q, valid, boxes, _qd in steps:
+            for s in range(S):
+                t = time.monotonic()
+                fut = eng.submit(f"cam{s}", q[s], valid[s], boxes[s])
+                sent[fut] = t
+                fut.add_done_callback(
+                    lambda f: done.setdefault(f, time.monotonic()))
+        eng.start()
+        eng.flush(timeout=FLUSH_S)
+    hist = _queue_hist(reg)
+    windows = reg.snapshot()["torr_windows_total"]["series"][0]["value"]
+    assert hist["count"] == windows == S * T == len(seen)
+    for (fut, _arrival, _ctx), wait in seen:
+        assert 0.0 <= wait <= done[fut] - sent[fut]
+    assert hist["sum"] == pytest.approx(sum(w for _e, w in seen))
+
+
+def test_sync_window_queue_seconds_once_per_window():
+    cfg = CFG
+    S, T = 3, 4
+    im = random_item_memory(jax.random.PRNGKey(0), cfg)
+    task_w = np.asarray(jax.random.uniform(jax.random.PRNGKey(1), (S, cfg.M)))
+    steps = _make_inputs(cfg, S, T)
+    reg = MetricsRegistry()
+    eng = StreamEngine(cfg, im, n_slots=S, metrics=reg)
+    seen = _spy_queue_waits(eng)
+    t_first = time.monotonic()
+    _submit_all(eng, task_w, steps, S)
+    eng.drain()
+    t_last = time.monotonic()
+    eng.summary()
+    hist = _queue_hist(reg)
+    assert hist["count"] == eng.stats.windows == S * T == len(seen)
+    assert all(0.0 <= w <= t_last - t_first for _e, w in seen)
+    # all submitted before the drain: a later step's windows waited longer
+    assert seen[-1][1] > seen[0][1]
+
+
+def test_dispatch_wait_recorded_when_async_engine_idles():
+    cfg = CFG
+    im = random_item_memory(jax.random.PRNGKey(0), cfg)
+    steps = _make_inputs(cfg, 1, 1)
+    reg = MetricsRegistry()
+    with AsyncStreamEngine(cfg, im, n_slots=1, metrics=reg) as eng:
+        eng.warmup()
+        eng.admit("cam0", np.ones((cfg.M,), np.float32))
+        time.sleep(0.2)                     # the dispatcher has no backlog
+        q, valid, boxes, _qd = steps[0]
+        eng.submit("cam0", q[0], valid[0], boxes[0]).result(timeout=FLUSH_S)
+        series = reg.snapshot()["torr_span_duration_seconds"]["series"]
+        waited = {s["labels"]["span"]: s for s in series}["dispatch_wait"]
+        # entered once per idle spell, not once per pass
+        assert waited["count"] == 1
+        assert waited["sum"] >= 0.15
+
+
+def test_phase_table_published_at_warmup_under_max_series():
+    from repro.obs.phases import PHASES, phase_table
+
+    cfg = CFG
+    im = random_item_memory(jax.random.PRNGKey(0), cfg)
+    reg = MetricsRegistry(max_series=len(PHASES))
+    eng = StreamEngine(cfg, im, n_slots=2, metrics=reg)
+    eng.warmup()
+    series = reg.snapshot()["torr_step_phase_ops"]["series"]
+    assert {s["labels"]["phase"] for s in series} == set(PHASES)
+    # the table of the executable the warm-up ran, one series a phase
+    S = eng.n_slots
+    args, kw = eng._step_call(eng._batch(
+        np.zeros((S, cfg.N_max, cfg.words), np.uint32),
+        np.zeros((S, cfg.N_max), bool),
+        np.zeros((S, cfg.N_max, 4), np.float32),
+        np.zeros((S,), np.int32)), eng._resolve_fused())
+    table = phase_table(eng._step.lower(*args, **kw).compile().as_text())
+    assert {op: s["labels"]["phase"] for s in series
+            for op in s["labels"]["ops"].split()} == table
+    assert all(s["value"] == len(s["labels"]["ops"].split())
+               for s in series)
+    # without a registry nothing is built and nothing fails
+    StreamEngine(cfg, im, n_slots=2, flight=FlightRecorder()).warmup()
