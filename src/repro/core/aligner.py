@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.phases import phase
+from . import hdc
 from .item_memory import (ItemMemory, bank_plane_sel, pmajor_bank_blocks,
                           word_mask)
 from .types import TorrConfig
@@ -45,6 +46,12 @@ def delta_indices(
     count [] int32 = true |Delta| over enabled words). Padding entries have
     weight 0 and idx 0; if count > budget the caller must escalate to full
     (TorR-on-TPU adaptation: static budget instead of a data-dependent FIFO).
+
+    The index list feeds the lowerings whose Eq. 6 reads only the flipped
+    rows: the ``switch`` step's ``delta_update`` kernel, the ``off``
+    oracle's :func:`delta_correct` and the ``compact`` decide passes. The
+    vmapped ``prefix`` step takes :func:`delta_count` and
+    :func:`delta_dense` instead.
     """
     xor = jnp.bitwise_xor(q_new_packed, q_old_packed)
     xor = jnp.where(wmask, xor, jnp.uint32(0))
@@ -69,6 +76,34 @@ def delta_indices(
     weight = jnp.where(new_bits == 1, 2, -2).astype(jnp.int32)
     weight = jnp.where(in_budget, weight, 0)
     return idx, weight, count
+
+
+def delta_count(q_new_packed: jax.Array, q_old_packed: jax.Array,
+                wmask: jax.Array) -> jax.Array:
+    """|Delta| over enabled words, int32 []: the count of
+    :func:`delta_indices` without its index list."""
+    xor = jnp.where(wmask, jnp.bitwise_xor(q_new_packed, q_old_packed),
+                    jnp.uint32(0))
+    return jnp.sum(jax.lax.population_count(xor).astype(jnp.int32))
+
+
+def delta_dense(q_new_packed: jax.Array, q_old_packed: jax.Array,
+                wmask: jax.Array, im: ItemMemory, D: int) -> jax.Array:
+    """Eq. 6's correction term as one dense masked matvec: int32 [M] with
+    ``corr[m] = sum_d mask_d * (q_new_d - q_old_d) * dmajor[d, m]``.
+
+    Every dim is read, flipped or not, so no index list is built: under
+    ``vmap`` the lanes' corrections are one [lanes, D] x [D, M] int8
+    product against the unbatched item memory. Equal to the correction of
+    :func:`delta_correct` over :func:`delta_indices` whenever
+    ``count <= budget`` (the only case Alg. 1 lets the delta path use):
+    the list then holds every flipped dim, and both sum the same integer
+    terms, each in {-2, 0, +2}, in int32."""
+    dmask = jnp.repeat(wmask, 32)
+    diff = jnp.where(dmask, hdc.unpack_bits(q_new_packed, D)
+                     - hdc.unpack_bits(q_old_packed, D), 0).astype(jnp.int8)
+    return jax.lax.dot_general(diff, im.dmajor, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
 
 
 def delta_correct(

@@ -12,10 +12,11 @@ selected path executes.
 By default the full path runs through the fused Pallas kernel family
 (``fused="switch"``/``"prefix"``, see :func:`torr_window_step`): the whole
 window's proposal batch takes one bank/plane-gated XNOR-popcount pass
-*before* the scan (the full branch then only gathers its row), and the
-delta branch's Eq. 6 correction streams through the scalar-prefetch
-kernel. ``fused="off"`` restores the per-proposal jnp-oracle executable,
-which the fused path is tested bit-identical against.
+*before* the scan (the full branch then only gathers its row). The delta
+branch's Eq. 6 correction streams through the scalar-prefetch kernel under
+``"switch"``, and is one dense masked matvec under the vmapped
+``"prefix"``. ``fused="off"`` restores the per-proposal jnp-oracle
+executable, which the fused path is tested bit-identical against.
 
 ``fused="compact"`` goes one step further (the reuse-aware dispatch): a
 metadata-only *decide* pass produces the window's path vector first, and
@@ -103,7 +104,7 @@ class WindowOutput:
 
 
 def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
-                   wmask, high, acc_full_all=None, fused_delta=False,
+                   wmask, high, acc_full_all=None, delta_form="gather",
                    decided=False):
     """Scan body over proposals for a fixed window context (all closures are
     window-constant traced values; ``planes`` is static — the latched plan).
@@ -113,6 +114,12 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
     branch then just gathers its row, so the scan never re-reads the item
     memory. ``None`` keeps the legacy per-proposal jnp oracle in-branch
     (the reference executable the fused path is tested against).
+
+    ``delta_form`` (static) is the Eq. 6 evaluation: ``"gather"`` the jnp
+    oracle over the flipped dims' index list (``aligner.delta_correct``),
+    ``"kernel"`` the same list through the ``delta_update`` kernel, and
+    ``"dense"`` one masked matvec over every dim
+    (``aligner.delta_dense``), which builds no list.
 
     ``decided=True`` is the compact dispatch's apply pass: the scan input
     additionally carries the decide pass's per-proposal decisions
@@ -134,15 +141,24 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
                 idx, rho, _ham = query_cache.nearest(cache, q_packed, cfg,
                                                      banks, planes)
             with phase("delta_search"):
-                d_idx, d_weight, d_count = al.delta_indices(
-                    q_packed, cache.packed[idx], wmask, cfg.delta_budget,
-                    cfg.D)
+                q_old = cache.packed[idx]
+                if delta_form == "dense":
+                    d_count = al.delta_count(q_packed, q_old, wmask)
+                else:
+                    d_idx, d_weight, d_count = al.delta_indices(
+                        q_packed, q_old, wmask, cfg.delta_budget, cfg.D)
             with phase("path_select"):
                 # Eq. 6 exactness: the cached accumulator is only
                 # delta-correctable under the exact (banks, planes) it
                 # was computed with
                 tag_ok = cache.acc_tag[idx] == tag
                 action = policy.select_path(rho, d_count, tag_ok, high, cfg)
+            if delta_form == "dense":
+                # outside the switch: under vmap every switch operand is
+                # broadcast to all lanes, the item memory included, while
+                # here the lanes share it in one [lanes, D] x [D, M] product
+                with phase("delta_apply"):
+                    corr = al.delta_dense(q_packed, q_old, wmask, im, cfg.D)
 
         def bypass_branch(cache):
             out = cache.out[idx]
@@ -150,7 +166,9 @@ def _proposal_body(cfg: TorrConfig, im: ItemMemory, task_w, banks, planes,
 
         def delta_branch(cache):
             with phase("delta_apply"):
-                if fused_delta:
+                if delta_form == "dense":
+                    acc = cache.acc[idx] + corr
+                elif delta_form == "kernel":
                     acc = al.delta_apply(cache.acc[idx], im, d_idx, d_weight)
                 else:
                     acc = al.delta_correct(cache.acc[idx], im, d_idx,
@@ -633,7 +651,8 @@ def torr_window_step(
     ``"prefix"`` is the vmap-shaped lowering the batched multi-stream step
     selects (one bank-prefix pass instead of a per-bank switch;
     ``ham_prefix_all`` carries the counts when the caller hoisted the
-    kernel over a whole stream batch); ``"compact"`` is the reuse-aware
+    kernel over a whole stream batch; Eq. 6 as one dense masked matvec,
+    ``aligner.delta_dense``); ``"compact"`` is the reuse-aware
     compact-then-compute dispatch: a metadata-only decide pass produces the
     path vector first, the fused scan runs only over the full-path
     proposals compacted to the static ``bucket_cap`` tier (see
@@ -688,7 +707,7 @@ def torr_window_step(
                 planes=planes, cap=cap, bucket_cap=btier)
         body = _proposal_body(cfg, im, state.task_weights, banks, planes,
                               wmask, high, acc_full_all=acc_rows,
-                              fused_delta=True, decided=True)
+                              delta_form="kernel", decided=True)
         cache, (outs, telem) = jax.lax.scan(
             body, state.cache, (q_packed_all, valid, arange) + dec)
     else:
@@ -701,13 +720,16 @@ def torr_window_step(
 
         # The scalar-prefetch delta kernel pays off where branch economy is
         # real (the "switch" lowering: only the selected path executes).
-        # Under the vmapped "prefix" lowering every lane computes all three
-        # branches, and a budget-deep scalar-streaming grid per lane is the
-        # wrong shape — the vectorized jnp gather-einsum IS the batched
-        # scatter-accumulate there, so the oracle form is kept deliberately.
+        # Under the vmapped "prefix" lowering every lane and proposal
+        # computes every branch, so Eq. 6 takes the form with no per-lane
+        # work of its own: the dense masked matvec, which builds no index
+        # list and reads the item memory once for all lanes. "off" keeps
+        # the jnp gather-einsum: it is the oracle.
+        delta_form = {"switch": "kernel", "prefix": "dense"}.get(fused,
+                                                                 "gather")
         body = _proposal_body(cfg, im, state.task_weights, banks, planes,
                               wmask, high, acc_full_all=acc_full_all,
-                              fused_delta=fused == "switch")
+                              delta_form=delta_form)
         cache, (outs, telem) = jax.lax.scan(
             body, state.cache, (q_packed_all, valid, arange))
 
@@ -936,7 +958,7 @@ def _multi_stream_compact_step(
             wmask = plan_word_mask(cfg, bk, planes)
         body = _proposal_body(cfg, im, st.task_weights, bk, planes,
                               wmask, h, acc_full_all=accs,
-                              fused_delta=True, decided=True)
+                              delta_form="kernel", decided=True)
         cache, (outs, telem) = jax.lax.scan(
             body, st.cache,
             (q, v, jnp.arange(cfg.N_max, dtype=jnp.int32)) + dec_s)

@@ -275,6 +275,84 @@ def test_fused_full_path_bit_identical_over_plan_grid(banks, planes, mode):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+# a budget small enough, and tau_q low enough, that every plan of PLANS
+# can hold a reuse at budget + 1 flips that Alg. 1 would take as delta
+EDGE_CFG = TorrConfig(D=1024, B=8, M=32, K=4, N_max=8, delta_budget=8,
+                      tau_q=0.3, feat_dim=64)
+
+
+def _drift(q_bip, flips, dmask, rng, n_off=7):
+    """Flip ``flips[s][n]`` plan-enabled dims of proposal n of stream s,
+    and ``n_off`` disabled ones, which |Delta| and Eq. 6 must not see."""
+    q = np.array(q_bip)
+    on, off = np.flatnonzero(dmask), np.flatnonzero(~dmask)
+    for s, row in enumerate(flips):
+        for n, c in enumerate(row):
+            dims = np.concatenate([rng.choice(on, c, replace=False),
+                                   rng.choice(off, min(n_off, off.size),
+                                              replace=False)])
+            q[s, n, dims] *= -1
+    return q
+
+
+@pytest.mark.parametrize("banks,planes", PLANS)
+def test_prefix_delta_budget_edge_bit_identical_over_plan_grid(banks,
+                                                                planes):
+    """The vmapped multi-stream ``prefix`` step evaluates Eq. 6 densely,
+    with no index list: bit-identical to the ``off`` oracle — scores,
+    ``best``, telemetry and the whole cache after every window — at the
+    delta budget's edge: reuses at exactly ``delta_budget`` flips (delta),
+    at ``delta_budget + 1`` (escalated to full), with zero flips, and with
+    flips in the plan's disabled dims, under every plan of the ladder."""
+    cfg, S = EDGE_CFG, 2
+    budget = cfg.delta_budget
+    im = random_item_memory(jax.random.PRNGKey(0), cfg)
+    task_w = jax.random.uniform(jax.random.PRNGKey(1), (S, cfg.M))
+    plan = _plan(banks, planes, cfg)
+    dmask = np.asarray(plan_dim_mask(cfg, banks, planes))
+    rng = np.random.default_rng(banks * 10 + planes)
+    step = jax.jit(pipeline.torr_multi_stream_step,
+                   static_argnames=("cfg", "plan", "fused"))
+    valid = jnp.asarray(np.arange(cfg.N_max) < cfg.K)[None].repeat(S, 0)
+    boxes = jnp.zeros((S, cfg.N_max, 4), jnp.float32)
+
+    edge = [0, budget, budget + 1, 3]            # flips of valid proposals
+    w0 = np.asarray(hdc.random_hv(jax.random.PRNGKey(2),
+                                  (S, cfg.N_max, cfg.D)))
+    w1 = _drift(w0, [edge, edge[::-1]], dmask, rng)
+    w2 = _drift(w1, [edge[1:] + edge[:1], edge[2:] + edge[:2]], dmask, rng)
+    windows = [(w0, [0, 0]), (w1, [0, 0]), (w2, [0, cfg.q_hi])]
+
+    res = {}
+    for fused in ("off", "prefix"):
+        st = pipeline.init_multi_stream_state(cfg, task_w)
+        res[fused] = []
+        for q_bip, qd in windows:
+            q = jax.vmap(jax.vmap(hdc.pack_bits))(jnp.asarray(q_bip))
+            st, out, tel = step(st, im, q, valid, boxes,
+                                jnp.asarray(qd, jnp.int32), cfg, plan=plan,
+                                fused=fused)
+            res[fused].append((st, out, tel))
+
+    tel1 = res["prefix"][1][2]
+    assert np.asarray(tel1.banks).tolist() == [banks] * S
+    assert np.asarray(tel1.delta_count)[:, :cfg.K].tolist() == [
+        edge, edge[::-1]]
+    assert np.asarray(tel1.path)[:, :cfg.K].tolist() == [
+        [PATH_DELTA, PATH_DELTA, PATH_FULL, PATH_DELTA],
+        [PATH_DELTA, PATH_FULL, PATH_DELTA, PATH_DELTA]]
+    for t, ((s0, o0, t0), (s1, o1, t1)) in enumerate(
+            zip(res["off"], res["prefix"])):
+        assert np.array_equal(np.asarray(o0.scores), np.asarray(o1.scores))
+        assert np.array_equal(np.asarray(o0.best), np.asarray(o1.best))
+        for f in TELEM_CHECK:
+            assert np.array_equal(np.asarray(getattr(t0, f)),
+                                  np.asarray(getattr(t1, f))), (t, f)
+        for a, b in zip(jax.tree_util.tree_leaves(s0.cache),
+                        jax.tree_util.tree_leaves(s1.cache)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), t
+
+
 @pytest.mark.parametrize("mode", ["switch", "prefix"])
 def test_fused_ragged_fallback_bit_identical(mode):
     """Ragged M (not a multiple of 8) rides the transparent oracle

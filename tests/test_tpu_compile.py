@@ -12,6 +12,7 @@ process may load the TPU library, and under pytest-xdist every worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.core.types import StreamBatch
 from repro.kernels import fused_window, xnor_popcount_sim
 from repro.kernels.delta_update import delta_update
 from repro.kernels.sign_project import sign_project
+from repro.obs import phases
 from repro.runtime import sharding as shd
 
 CFG = torr_edge()
@@ -174,6 +176,31 @@ def test_multi_stream_step_compiles(one_chip, tpu_lowering, fused, kw):
         lambda st, m, b: pipeline.torr_stream_batch_step(
             st, m, b, CFG, fused=fused, **kw), state, im, batch)
     assert "tpu_custom_call" in text
+
+
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) [a-z][\w\-]*\(")
+
+
+def test_prefix_step_delta_phases_build_no_index_list(one_chip,
+                                                     tpu_lowering):
+    """The served ``prefix`` step evaluates Eq. 6 as one dense masked
+    matvec: no op of its ``delta_search`` or ``delta_apply`` phases has a
+    result dimension of ``delta_budget``, the length of the flipped-dim
+    list a per-lane index search and row gather would build."""
+    state, im, batch = _step_args(lambda a, streamed: one_chip)
+    text = _compile(
+        lambda st, m, b: pipeline.torr_stream_batch_step(
+            st, m, b, CFG, fused="prefix"), state, im, batch)
+    results = dict(m.groups() for m in map(_RESULT.match, text.splitlines())
+                   if m is not None)
+    delta_ops = {name: results[name]
+                 for name, ph in phases.phase_table(text).items()
+                 if ph in ("delta_search", "delta_apply")}
+    assert delta_ops
+    for name, shape in delta_ops.items():
+        dims = [int(d) for group in re.findall(r"\[([\d,]*)\]", shape)
+                for d in group.split(",") if d]
+        assert CFG.delta_budget not in dims, (name, shape)
 
 
 def test_stream_sharded_step_compiles(topo, tpu_lowering):
