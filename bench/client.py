@@ -5,7 +5,8 @@ Never imports JAX: the server process holds the chip. Started by
 ``bench/run.py`` with a JSON job as its one argument; it
 
 1. opens every stream's session and sends its ``warm`` windows, each one
-   after the reply to the last;
+   after the reply to the last; a window's fields come from the
+   configuration's input module (``bench/inputs``), from the seed;
 2. prints ``ready`` and waits for ``go <t_open>`` on its standard input
    (``t_open`` on ``time.monotonic``, which both processes share);
 3. sends windows until ``t_open + seconds``: in a closed loop the next one
@@ -25,30 +26,36 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from bench import inputs  # noqa: E402
 from bench import traffic as tr  # noqa: E402
-from bench.wire import Client, encode  # noqa: E402
+from bench.wire import Client  # noqa: E402
 
 
 def _session(job: dict, i: int) -> tuple[str, str, int]:
     return (f"t{i % job['tenants']}", f"c{i}", i % job["tasks"])
 
 
-def _stream(job: dict, i: int, go: threading.Event, start: dict,
+def window_body(sid: str, seq: int, deadline_ms: float,
+                fields: dict) -> dict:
+    """The ``POST /v1/window`` body: the session's keys, then the
+    window's fields."""
+    return {"session": sid, "seq": seq, "deadline_ms": deadline_ms, **fields}
+
+
+def _stream(job: dict, mod, i: int, go: threading.Event, start: dict,
             ready: threading.Barrier, out: dict) -> None:
     tenant, stream, task = _session(job, i)
     sid = f"{tenant}/{stream}"
     cli = Client(job["host"], job["port"], job["timeout_s"])
-    gen = tr.StreamGen(job["traffic"], job["n_max"], job["D"], job["seed"], i)
+    wins = mod.windows(job["traffic"], job["config"], job["seed"], i)
     recs, unsent = [], []
     out[i] = (recs, unsent)
     seq = 0
 
     def send(t_sched: float) -> float:
         nonlocal seq
-        q, valid, boxes = gen.next()
-        body = {"session": sid, "seq": seq, "deadline_ms": job["deadline_ms"],
-                "q": encode(q), "valid": encode(valid),
-                "boxes": encode(boxes)}
+        body = window_body(sid, seq, job["deadline_ms"],
+                           mod.fields(next(wins)))
         t_send = time.monotonic()
         try:
             status, reply = cli.request("POST", "/v1/window", body)
@@ -93,6 +100,7 @@ def _stream(job: dict, i: int, go: threading.Event, start: dict,
 
 def main() -> int:
     job = json.loads(sys.argv[1])
+    mod = inputs.load(job["input"])
     n = job["streams"]
     go, start, out = threading.Event(), {}, {}
     ready = threading.Barrier(n + 1)
@@ -100,7 +108,7 @@ def main() -> int:
         start["arrivals"] = tr.schedule(job["traffic"], n, job["seconds"],
                                         job["seed"])
     threads = [threading.Thread(target=_stream, name=f"client-{i}",
-                                args=(job, i, go, start, ready, out),
+                                args=(job, mod, i, go, start, ready, out),
                                 daemon=True) for i in range(n)]
     for th in threads:
         th.start()

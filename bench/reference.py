@@ -52,7 +52,14 @@ def select_banks(cfg: dict, n_valid: int, queue_depth: int) -> int:
 
 
 def topk_stable(s: np.ndarray, k: int) -> np.ndarray:
-    return np.argsort(-s, kind="stable")[:k]
+    """The first k of a stable descending sort: the k largest, lower index
+    first on ties. Only the values at or above the k-th largest are
+    sorted."""
+    if s.size <= k:
+        return np.argsort(-s, kind="stable")[:k]
+    kth = np.partition(s, s.size - k)[s.size - k]
+    top = np.flatnonzero(s >= kth)
+    return top[np.argsort(-s[top], kind="stable")[:k]]
 
 
 class Stream:
@@ -63,6 +70,7 @@ class Stream:
         self.cfg = cfg
         K, M, W = cfg["K"], cfg["M"], cfg["D"] // 32
         self.codes = codes                       # int8 [M, D]
+        self.codes_t = np.ascontiguousarray(codes.T)     # int8 [D, M]
         # f32 holds every ±1 dot product exactly (|dot| <= D < 2**24);
         # streams of one run may share it
         self.codes_f = codes.astype(np.float32) if codes_f is None \
@@ -76,6 +84,48 @@ class Stream:
         self.out = np.zeros((K, M), np.float32)
         self.key = np.full((K, cfg["top_k"]), -1, np.int64)
         self.margin = np.zeros(K, np.float32)
+        # each proposal row's last full dot products, with the query and
+        # the number of words they were taken over (0: none yet)
+        self.dot = np.zeros((cfg["N_max"], M), np.int64)
+        self.dot_q = np.zeros((cfg["N_max"], W), np.uint32)
+        self.dot_words = np.zeros(cfg["N_max"], np.int64)
+
+    def _flip_sum(self, flipped: np.ndarray, sign: np.ndarray) -> np.ndarray:
+        """Sum over the ``flipped`` dimensions of twice the new ``sign``
+        times the concepts' column there: what Eq. 6 adds to every
+        concept's dot product (int64 [M]; f32 holds it exactly, since
+        its magnitude is at most 2 D < 2**24)."""
+        return np.rint((2 * sign).astype(np.float32)
+                       @ self.codes_t[flipped].astype(np.float32)
+                       ).astype(np.int64)
+
+    def _full_dots(self, q: np.ndarray, rows: np.ndarray,
+                   words: int) -> np.ndarray:
+        """The dot products over the first ``words * 32`` dimensions of
+        each of ``rows``' queries with every concept (int64 [N_max, M],
+        by row). A row's last products are corrected over the dimensions
+        flipped since they were taken, each flip adding twice the new
+        sign times the concepts' column there; where many flipped, or
+        D' changed, they are taken afresh in one f32 matmul. Both are
+        exact on ±1 integers."""
+        dims = words * 32
+        diff = q[rows, :words] ^ self.dot_q[rows, :words]
+        n_flip = np.bitwise_count(diff).sum(axis=1, dtype=np.int64)
+        afresh = (self.dot_words[rows] != words) | (n_flip > dims // 16)
+        new = rows[afresh]
+        if new.size:
+            q_bip = unpack_bits(q[new, :words], dims)
+            self.dot[new] = np.rint(
+                q_bip.astype(np.float32) @ self.codes_f[:, :dims].T
+            ).astype(np.int64)
+        moved = ~afresh & (n_flip > 0)
+        for i, d in zip(rows[moved], diff[moved]):
+            flipped = np.flatnonzero(unpack_bits(d, dims) > 0)
+            self.dot[i] += self._flip_sum(
+                flipped, unpack_bits(q[i, :words], dims)[flipped])
+        self.dot_q[rows] = q[rows]
+        self.dot_words[rows] = words
+        return self.dot
 
     def _write(self, slot: int, q, acc, out, key, margin, tag) -> None:
         self.age += 1
@@ -102,10 +152,7 @@ class Stream:
         paths = [0, 0, 0]
         rows = np.flatnonzero(valid)
         dims = words * 32
-        q_bip = unpack_bits(q[rows], cfg["D"])[:, :dims]
-        full_dot = dict(zip(rows.tolist(), np.rint(
-            q_bip.astype(np.float32) @ self.codes_f[:, :dims].T
-        ).astype(np.int64)))
+        full_dot = self._full_dots(q, rows, words)
         for i in rows:
             qi = q[i]
             ham = np.bitwise_count(self.packed[:, :words] ^ qi[:words]) \
@@ -131,8 +178,7 @@ class Stream:
                 new = unpack_bits(qi, cfg["D"])[:dims]
                 old = unpack_bits(self.packed[idx], cfg["D"])[:dims]
                 flipped = np.flatnonzero(new != old)
-                acc = self.acc[idx] + self.codes[:, flipped].astype(
-                    np.int64) @ (2 * new[flipped].astype(np.int64))
+                acc = self.acc[idx] + self._flip_sum(flipped, new[flipped])
             else:
                 acc = full_dot[i]
             s = acc.astype(np.float32) / d_eff

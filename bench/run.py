@@ -6,20 +6,24 @@
 The cell (a ``workloads`` entry of ``BENCHMARK.json``) names a
 configuration, ``bench/configs/<config>.json``, and a traffic mix,
 ``bench/traffic/<traffic>.json``; every metric is a reader
-``bench/metrics/<metric>.py``. All are found by name.
+``bench/metrics/<metric>.py``. All are found by name. The configuration
+names its input module (``bench/inputs``: what its windows are, the data
+and stack they are served by, and the reference that replays them), or
+gets the default one, packed queries.
 
-A run builds the served stack (``bench/stack.py``) from the seed, starts
-the clients in a child process that never imports JAX (``bench/client.py``),
-lets every stream's warm windows through, then measures for ``--seconds``.
+A run makes the data and builds the served stack from the seed through the
+input module, starts the clients in a child process that never imports JAX
+(``bench/client.py``), lets every stream's warm windows through, then
+measures for ``--seconds``.
 ``setup_s`` is everything from JAX's devices in hand to the window's open.
 With ``--trace 1`` the
 profiler records a slice in the middle of the window and the run reports
 the cell's per-layer metrics instead of its end-to-end ones.
 
 Once the window has closed and the stack is down, the replies are checked
-against the plain reference (``bench/reference.py``): every window of
-every stream answer for answer, and the path counters against the
-reference's decisions. Each number
+against the input module's plain reference: every window of every stream
+answer for answer, and the path counters against the reference's
+decisions, one stream to a spawned worker process. Each number
 compared is printed beside its limit, on standard error and under the
 result line's last key, ``checks``.
 
@@ -32,9 +36,11 @@ passes it.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import importlib.util
 import json
+import multiprocessing
 import os
 import shutil
 import subprocess
@@ -69,6 +75,8 @@ class Spec:
     metric entries, read from a checkout root."""
 
     def __init__(self, root: str, workload: str):
+        from bench import inputs
+
         self.root = root
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             self.bench = json.load(f)
@@ -80,6 +88,7 @@ class Spec:
         configs = {c["name"]: c for c in self.bench["configs"]}
         with open(os.path.join(root, configs[self.cell["config"]]["file"])) as f:
             self.config = json.load(f)
+        self.input = inputs.path_of(root, self.config)
         with open(os.path.join(root, "bench", "traffic",
                                self.cell["traffic"] + ".json")) as f:
             self.traffic = json.load(f)
@@ -209,38 +218,82 @@ def _window_latencies(records, unsent, loop, t_open, t_close):
     return lat, answered
 
 
-def check(spec: Spec, codes, task_bank, records, server_paths, seed):
-    """The comparison with the plain reference, every window of every
-    stream: ``{name: (value, limit)}`` and the number of windows compared."""
-    from bench import reference as ref
-    from bench import traffic as tr
+def check_stream(job: tuple) -> tuple:
+    """Replay one stream through its input module's reference and compare
+    every window answered 200: ``(failed, wrong, compared, paths)``.
+    ``job`` is ``(input path, config, traffic, seed, stream, data as numpy
+    arrays, the stream's records in order of seq)``."""
+    from bench import inputs
 
-    cfg = spec.config["torr"]
+    path, config, traffic, seed, stream, data, recs = job
+    mod = inputs.load(path)
+    # a session or a window went missing
+    failed = int(len(recs) < traffic["warm"]
+                 or [r[1] for r in recs] != list(range(len(recs))))
+    replay = mod.reference(config, data, stream)
+    wins = mod.windows(traffic, config, seed, stream)
+    wrong = compared = 0
+    paths = np.zeros(3, np.int64)
+    for r in recs:
+        scores, p = replay(next(wins))
+        paths += p
+        if r[5] == 200:
+            best, digest = mod.answer(scores)
+            compared += 1
+            wrong += best != r[6] or digest != r[7]
+    return failed, wrong, compared, paths.tolist()
+
+
+@contextlib.contextmanager
+def _environ(**env):
+    saved = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+def check(spec: Spec, data: dict, records, server_paths, seed,
+          workers: int = 0):
+    """The comparison with the plain reference, every window of every
+    stream: ``{name: (value, limit)}``, the number of windows compared and
+    the reference's path counts. Each stream is replayed on its own, in
+    ``workers`` spawned processes, or here one after another where
+    ``workers`` is 0 (the serial form); the sums are the same."""
     n_streams = spec.traffic["streams"]
     by_stream = {i: [] for i in range(n_streams)}
     failed = 0
     for r in records:
         by_stream[r[0]].append(r)
         failed += r[5] != 200
-    codes_f = codes.astype(np.float32)
+    jobs = [(spec.input, spec.config, spec.traffic, seed, i, data,
+             sorted(by_stream[i], key=lambda r: r[1]))
+            for i in range(n_streams)]
+    if workers:
+        # spawned, never forked from the process that holds the chip; one
+        # BLAS thread a worker, since the workers fill the cores
+        with _environ(OPENBLAS_NUM_THREADS="1", OMP_NUM_THREADS="1",
+                      MKL_NUM_THREADS="1"):
+            pool = multiprocessing.get_context("spawn").Pool(workers)
+        try:
+            results = pool.map(check_stream, jobs, chunksize=1)
+        finally:
+            pool.terminate()
+            pool.join()
+    else:
+        results = map(check_stream, jobs)
     wrong = compared = 0
     paths = np.zeros(3, np.int64)
-    for i in range(n_streams):
-        recs = sorted(by_stream[i], key=lambda r: r[1])
-        if len(recs) < spec.traffic["warm"] or \
-                [r[1] for r in recs] != list(range(len(recs))):
-            failed += 1                 # a session or a window went missing
-        task = i % spec.config["tasks"]
-        stream = ref.Stream(cfg, codes, task_bank[task], codes_f)
-        gen = tr.StreamGen(spec.traffic, cfg["N_max"], cfg["D"], seed, i)
-        for r in recs:
-            q, valid, _boxes = gen.next()
-            scores, p = stream.window(q, valid)
-            paths += p
-            if r[5] == 200:
-                best, digest = ref.answer(scores)
-                compared += 1
-                wrong += best != r[6] or digest != r[7]
+    for f, w, c, p in results:
+        failed += f
+        wrong += w
+        compared += c
+        paths += p
     gap = int(np.abs(paths - np.asarray(server_paths)).sum())
     checks = {"failed_windows": (failed, 0), "wrong_answers": (wrong, 0),
               "path_count_gap": (gap, 0)}
@@ -277,13 +330,14 @@ def run(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
             compiles[0] += 1
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    from bench import stack as stk
+    from bench import inputs
     from bench import trace_reduce as trr
     from bench import work
 
     config, traffic = spec.config, spec.traffic
-    codes, task_bank = stk.make_data(config, args.seed)
-    codes_h, task_h = np.asarray(codes), np.asarray(task_bank)
+    mod = inputs.load(spec.input)
+    data = mod.make_data(config, args.seed)
+    data_h = {k: np.asarray(v) for k, v in data.items()}
     t_data = time.monotonic()
     plan = None
     if args.control == "planes":
@@ -291,13 +345,13 @@ def run(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
         t = config["torr"]
         plan = KnobPlan(banks=t["B"], planes=t["bit_planes"] - 1,
                         plane_total=t["bit_planes"])
-    stack = stk.build(config, codes, task_bank, plan=plan)
+    stack = mod.build(config, data, plan=plan)
     t_stack = time.monotonic()
-    del codes
+    del data
     labels, restore = _annotate(stack) if args.trace else ((), None)
     stack.gw.start()
     job = {"host": "127.0.0.1", "port": stack.gw.port, "traffic": traffic,
-           "n_max": config["torr"]["N_max"], "D": config["torr"]["D"],
+           "input": spec.input, "config": config,
            "seed": args.seed, "streams": traffic["streams"],
            "tenants": traffic["tenants"], "tasks": config["tasks"],
            "warm": traffic["warm"], "seconds": args.seconds,
@@ -396,11 +450,14 @@ def run(argv=None, root: str = ROOT, require_chip: bool = True) -> int:
     server_paths = [_series_value(snap_end, "torr_path_total", {"path": p})
                     for p in ("bypass", "delta", "full")]
     t_ref = time.monotonic()
-    checks, compared, ref_paths = check(spec, codes_h, task_h, records,
-                                        server_paths, args.seed)
+    # one worker a stream: each replays in order, and the cores share them
+    n_workers = traffic["streams"]
+    checks, compared, ref_paths = check(spec, data_h, records, server_paths,
+                                        args.seed, workers=n_workers)
     log(f"reference: {compared} windows compared in "
-        f"{time.monotonic() - t_ref:.3f} s; paths server {server_paths} "
-        f"reference {ref_paths}")
+        f"{time.monotonic() - t_ref:.3f} s by {n_workers} worker processes"
+        f" on {len(os.sched_getaffinity(0))} cores;"
+        f" paths server {server_paths} reference {ref_paths}")
     correct = compared > 0 and all(v <= lim for v, lim in checks.values())
 
     device = {"platform": devs[0].platform, "kind": kind, "count": chips,

@@ -78,6 +78,35 @@ def test_planted_fault_is_not_correct(root, capsys, monkeypatch, fault,
     assert res["checks"][caught_by]["value"] > 0
 
 
+@pytest.mark.parametrize("fault,caught_by", [
+    (None, None),
+    ("state_unchanged", "path_count_gap"),
+    ("half_batch", "wrong_answers"),
+    ("answer_altered", "wrong_answers"),
+])
+def test_pooled_check_agrees_with_serial(root, capsys, monkeypatch, fault,
+                                         caught_by):
+    """The check in worker processes sums to what the serial check gives
+    on the same records: windows compared, path counts and verdicts."""
+    seen = []
+    orig = bench_run.check
+
+    def both(spec, data, records, server_paths, seed, workers=0):
+        serial = orig(spec, data, records, server_paths, seed)
+        pooled = orig(spec, data, records, server_paths, seed, workers=3)
+        seen.append((serial, pooled))
+        return pooled
+    monkeypatch.setattr(bench_run, "check", both)
+    if fault is not None:
+        _plant(monkeypatch, fault)
+    res = _run(root, capsys)
+    (serial, pooled), = seen
+    assert pooled == serial and serial[1] > 0
+    assert res["correct"] is (fault is None)
+    if fault is not None:
+        assert serial[0][caught_by][0] > 0
+
+
 def test_no_chip_exits_nonzero_without_a_result(root, capsys):
     rc = bench_run.run(["--workload", "toy_coherent", "--seed", "1",
                         "--seconds", "1"], root=root)

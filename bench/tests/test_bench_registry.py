@@ -1,5 +1,6 @@
-"""A configuration, a traffic mix and a per-layer metric are found by name
-once added as new files, with no file that was there edited."""
+"""A configuration, its input module, a traffic mix and a per-layer metric
+are found by name once added as new files, with no file that was there
+edited."""
 from __future__ import annotations
 
 import hashlib
@@ -9,6 +10,7 @@ import os
 import numpy as np
 import pytest
 
+from bench import inputs
 from bench import run as bench_run
 from bench_toy import make_root
 
@@ -31,8 +33,12 @@ def test_new_files_are_found_by_name(tmp_path):
         cfg = json.load(f)
     cfg["name"] = "toy_wide"
     cfg["torr"]["K"] = 16
+    cfg["input"] = "bench/inputs/toy_wide.py"
     with open(os.path.join(root, "bench/configs/toy_wide.json"), "w") as f:
         json.dump(cfg, f)
+    with open(os.path.join(root, "bench/inputs/toy_wide.py"), "w") as f:
+        f.write("from bench.inputs.packed import *  # noqa: F403\n"
+                "WIDE = True\n")
     with open(os.path.join(root, "bench/traffic/toy_burst.json"), "w") as f:
         json.dump({"loop": "open", "streams": 2, "tenants": 1, "warm": 1,
                    "valid": {"p": 0.5}, "content": {"bit_flips": 2},
@@ -59,6 +65,11 @@ def test_new_files_are_found_by_name(tmp_path):
 
     spec = bench_run.Spec(root, "toy_wide.burst")
     assert spec.config["torr"]["K"] == 16
+    assert spec.input == os.path.join(root, "bench/inputs/toy_wide.py")
+    mod = inputs.load(spec.input)
+    assert mod.WIDE and callable(mod.windows) and callable(mod.reference)
+    assert bench_run.Spec(root, "toy_coherent").input == os.path.join(
+        root, inputs.DEFAULT)
     assert spec.traffic["content"] == {"bit_flips": 2}
     assert [m["name"] for m in spec.per_layer] == ["toy_windows"]
     assert {m["name"] for m in spec.end_to_end} == {
