@@ -49,6 +49,13 @@ Every lowering names its phases with the same named scopes
 ``reason``, ``cache_write``, ``finish``; ``prefix_scan`` in the aligner),
 so each op of the compiled step carries its phase in its metadata. Scopes
 change no op and no result.
+
+A step given a ``projection`` R (int8 ``[D, feat_dim]``) serves feature
+windows: its queries are int8 features ``[..., N_max, feat_dim]``, and
+its first phase, ``encode``, turns them into packed query words
+``pack(sign(R z))`` (paper Sec. 3.2; :func:`encode_queries`) inside the
+same executable. Without one (the default) the step takes packed words
+and has no ``encode`` op.
 """
 from __future__ import annotations
 
@@ -58,6 +65,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops as kops
+from ..kernels import ref as kref
 from ..obs.phases import phase
 from . import aligner as al
 from . import policy, query_cache, reasoner
@@ -620,6 +629,22 @@ def _resolve_bucket_cap(bucket_cap, plan, n_rows: int) -> int:
     return cap
 
 
+def encode_queries(z: jax.Array, projection: jax.Array,
+                   fused) -> jax.Array:
+    """Packed query words uint32 ``[..., D//32]`` = pack(sign(R z)) of
+    features ``z`` ``[..., feat_dim]``, sign(0) -> +1, under the ``encode``
+    phase: every row in one pass, through ``kernels.ops.encode_packed``
+    (the ``sign_project_pack`` kernel on the Pallas lowering), or through
+    the ``kernels.ref`` oracle where ``fused="off"``. With int8 features
+    and an int8 R each projection is an exact int32, so both agree bit for
+    bit."""
+    with phase("encode"):
+        flat = z.reshape(-1, z.shape[-1])
+        words = (kref.sign_project_pack_ref(flat, projection)
+                 if fused == "off" else kops.encode_packed(flat, projection))
+        return words.reshape(z.shape[:-1] + words.shape[-1:])
+
+
 def torr_window_step(
     state: TorrState,
     im: ItemMemory,
@@ -633,8 +658,13 @@ def torr_window_step(
     ham_prefix_all=None,       # int32 [N_max, cap, M] hoisted prefix counts
     bucket_cap=None,           # static compact-dispatch bucket capacity
     decide=None,               # static: "batched" | "scan" (compact only)
+    projection=None,           # int8 [D, feat_dim]: q_packed_all is features
 ) -> tuple[TorrState, WindowOutput, WindowTelemetry]:
     """Process one window; returns (new_state, detections, telemetry).
+
+    ``projection`` (R, int8 ``[D, feat_dim]``) makes ``q_packed_all`` the
+    window's int8 features ``[N_max, feat_dim]``, encoded first
+    (:func:`encode_queries`); None (the default) takes packed words.
 
     ``plan`` is a static :class:`repro.control.plan.KnobPlan` latched by the
     QoS control plane: it caps Alg. 1's bank choice (``min`` — the full cap
@@ -681,6 +711,8 @@ def torr_window_step(
         fused = "switch"
     if fused not in _FUSED_MODES:
         raise ValueError(f"fused={fused!r} not in {_FUSED_MODES}")
+    if projection is not None:
+        q_packed_all = encode_queries(q_packed_all, projection, fused)
     planes, cap, cfg = _plan_static(plan, cfg)
     with phase("policy"):
         n_valid = jnp.sum(valid.astype(jnp.int32))
@@ -806,8 +838,14 @@ def torr_multi_stream_step(
     fused=None,                # static: "switch"|"prefix"|"compact"|"off"
     bucket_cap=None,           # static compact-dispatch bucket capacity
     decide=None,               # static: "batched" | "scan" (compact only)
+    projection=None,           # int8 [D, feat_dim]: q_packed_all is features
 ) -> tuple[TorrState, WindowOutput, WindowTelemetry]:
     """One compiled step over S streams' windows.
+
+    ``projection`` (R, int8 ``[D, feat_dim]``) makes ``q_packed_all`` the
+    windows' int8 features ``[S, N_max, feat_dim]``: the step encodes all
+    S x N_max rows in one pass first (:func:`encode_queries`), then runs
+    as on packed words. None (the default) takes packed words.
 
     All S windows of one batched step share the latched ``plan`` (the
     window-latched register analogue: one plan per dispatch); each window's
@@ -853,6 +891,8 @@ def torr_multi_stream_step(
     """
     if fused is None:
         fused = "switch" if serial else "prefix"
+    if projection is not None:
+        q_packed_all = encode_queries(q_packed_all, projection, fused)
 
     if fused == "compact":
         return _multi_stream_compact_step(
@@ -978,11 +1018,12 @@ def _multi_stream_compact_step(
 def torr_stream_batch_step(
     state: TorrState, im: ItemMemory, batch: StreamBatch, cfg: TorrConfig,
     serial: bool = False, plan=None, fused=None, bucket_cap=None,
-    decide=None,
+    decide=None, projection=None,
 ) -> tuple[TorrState, WindowOutput, WindowTelemetry]:
-    """`torr_multi_stream_step` over a packed :class:`StreamBatch`."""
+    """`torr_multi_stream_step` over a :class:`StreamBatch` (whose
+    ``q_packed`` holds features where ``projection`` is given)."""
     return torr_multi_stream_step(
         state, im, batch.q_packed, batch.valid, batch.boxes,
         batch.queue_depth, cfg, serial=serial, plan=plan, fused=fused,
-        bucket_cap=bucket_cap, decide=decide,
+        bucket_cap=bucket_cap, decide=decide, projection=projection,
     )

@@ -145,6 +145,8 @@ class StreamBatch:
     """
 
     q_packed: jax.Array     # uint32 [S, N_max, D//32] proposal query HVs
+                            # (int8 [S, N_max, feat_dim] features for a
+                            # step that encodes them)
     valid: jax.Array        # bool   [S, N_max]
     boxes: jax.Array        # f32    [S, N_max, 4]
     queue_depth: jax.Array  # int32  [S] per-stream backlog

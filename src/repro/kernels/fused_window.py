@@ -29,8 +29,9 @@ instead (``core.aligner.full_scores_all`` is the traced-banks shim):
     dispatching to the scalar-prefetch ``delta_update`` kernel so the
     bypass/delta/full trio all avoid the jnp oracle inside the jitted step.
   * :func:`sign_project_pack` — encode front-end: sign-projection fused
-    with bit-packing, writing uint32 words directly (neither the f32
-    projection nor the int8 bipolar code round-trips HBM).
+    with bit-packing, writing uint32 words directly (neither the
+    projection nor the int8 bipolar code round-trips HBM); f32 operands,
+    or int8 features against an int8 projection with int32 accumulation.
 
 Every kernel keeps the oracle fallback contract of ``kernels.ops``: ragged
 shapes transparently use the jnp reference, so callers never see a shape
@@ -369,16 +370,17 @@ def delta_apply(
 # encode front-end: sign-projection fused with bit-packing
 # ---------------------------------------------------------------------------
 
-def _pack_kernel(r_ref, z_ref, out_ref):
-    """Dims on sublanes, queries on lanes: ``y^T = R_tile z^T`` is [TD, TN];
-    each group of 32 consecutive dims (a sublane-aligned split) folds into
+def _pack_kernel(r_ref, z_ref, out_ref, *, acc_dtype):
+    """Dims on sublanes, queries on lanes: ``y^T = R_tile z^T`` is [TD, TN],
+    accumulated in ``acc_dtype`` (f32, or int32 for int8 operands); each
+    group of 32 consecutive dims (a sublane-aligned split) folds into
     one word by shifting each sign bit to its position and summing the
     disjoint bits — the int32 sum of disjoint powers of two is their OR,
     bit 31 included."""
     y = jax.lax.dot_general(r_ref[...], z_ref[...], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [TD, TN]
+                            preferred_element_type=acc_dtype)    # [TD, TN]
     td, tn = y.shape
-    bits = (y >= 0.0).astype(jnp.int32).reshape(td // 32, 32, tn)
+    bits = (y >= 0).astype(jnp.int32).reshape(td // 32, 32, tn)
     shifts = jax.lax.broadcasted_iota(jnp.int32, (td // 32, 32, tn), 1)
     words = jnp.sum(jnp.left_shift(bits, shifts), axis=1)       # [TD/32, TN]
     out_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
@@ -386,14 +388,18 @@ def _pack_kernel(r_ref, z_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sign_project_pack(
-    z: jax.Array,    # f32 [N, d]
-    R: jax.Array,    # f32 [D, d]
+    z: jax.Array,    # f32 [N, d], or int8 with an int8 R
+    R: jax.Array,    # f32 [D, d], or int8
     *,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Packed query words uint32 [N, D//32] = pack(sign(z @ R.T)).
+    """Packed query words uint32 [N, D//32] = pack(sign(z @ R.T)), with
+    sign(0) -> +1.
 
-    Extends the ``sign_project`` kernel one stage further: the f32
+    Two operand forms: f32 (f32 accumulation), and int8 features against
+    an int8 projection (int32 accumulation on the MXU, so every
+    projection is an exact integer while ``d * max|z| * max|R| < 2**31``).
+    Extends the ``sign_project`` kernel one stage further: the
     projection *and* the bipolar code both stay in VMEM; only the
     1-bit/dim packed words are written back (a 32x cut on the
     encoder->aligner hand-off, previously left to XLA as a separate pass).
@@ -405,10 +411,12 @@ def sign_project_pack(
     N, d = z.shape
     D, d2 = R.shape
     assert d == d2 and D % 32 == 0
+    int8 = z.dtype == R.dtype == jnp.int8
+    acc_dtype = jnp.int32 if int8 else jnp.float32
     td = 256 if D % 256 == 0 else D
     tn = lane_tile(N, TW)
     words_t = pl.pallas_call(
-        _pack_kernel,
+        functools.partial(_pack_kernel, acc_dtype=acc_dtype),
         grid=(D // td, N // tn),
         name="sign_project_pack",
         in_specs=[

@@ -152,18 +152,20 @@ def fused_similarity(
 
 
 def encode_packed(
-    z: jax.Array,   # f32 [N, d] encoder features
-    R: jax.Array,   # f32 [D, d] projection
+    z: jax.Array,   # f32 [N, d] encoder features, or int8 with an int8 R
+    R: jax.Array,   # f32 [D, d] projection, or int8
     *,
     interpret: bool | None = None,
     use_kernel: bool = True,
 ) -> jax.Array:
     """Fused encode front-end: uint32 [N, D//32] = pack(sign(z @ R.T)).
 
-    On the Pallas lowering one kernel (``fused_window.sign_project_pack``)
-    keeps the f32 projection *and* the int8 bipolar code in VMEM; only the
-    packed words are written. Off-TPU (and off-tile) the jnp form runs —
-    XLA fuses the sign into the matmul there, and the pack is cheap."""
+    f32 operands, or int8 features against an int8 projection (int32
+    accumulation: exact). On the Pallas lowering one kernel
+    (``fused_window.sign_project_pack``) keeps the projection *and* the
+    int8 bipolar code in VMEM; only the packed words are written. Off-TPU
+    (and off-tile) the jnp form runs — XLA fuses the sign into the matmul
+    there, and the pack is cheap."""
     N, _ = z.shape
     D, _ = R.shape
     lowering = fused_window._pallas_lowering(interpret)

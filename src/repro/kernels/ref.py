@@ -40,7 +40,8 @@ def bank_prefix_hamming_ref(
 
 
 def sign_project_pack_ref(z: jax.Array, R: jax.Array) -> jax.Array:
-    """uint32 [N, D//32] — `fused_window.sign_project_pack`."""
+    """uint32 [N, D//32] — `fused_window.sign_project_pack`, in either
+    operand form."""
     from ..core import hdc   # function-level: core imports this package
 
     return hdc.pack_bits(sign_project_ref(z, R))
@@ -55,6 +56,11 @@ def delta_update_ref(
 
 
 def sign_project_ref(z: jax.Array, R: jax.Array) -> jax.Array:
-    """int8 [N, D] = sign(z @ R.T), sign(0) -> +1."""
-    y = z.astype(jnp.float32) @ R.astype(jnp.float32).T
-    return jnp.where(y >= 0.0, 1, -1).astype(jnp.int8)
+    """int8 [N, D] = sign(z @ R.T), sign(0) -> +1. int8 ``z`` and ``R``
+    project with int32 accumulation (exact); any other dtypes in f32."""
+    if z.dtype == jnp.int8 and R.dtype == jnp.int8:
+        y = jax.lax.dot_general(z, R, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.int32)
+    else:
+        y = z.astype(jnp.float32) @ R.astype(jnp.float32).T
+    return jnp.where(y >= 0, 1, -1).astype(jnp.int8)

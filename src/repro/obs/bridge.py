@@ -127,6 +127,9 @@ class StepObserver:
         self._c_delta = r.counter(
             "torr_delta_dims_total",
             "Summed |Delta| dimensions corrected via Eq. 6.")
+        self._c_encoded = r.counter(
+            "torr_encoded_rows_total",
+            "Valid feature rows encoded on the device, q = sign(R z).")
         self._c_reasoner = r.counter(
             "torr_reasoner_active_total",
             "Proposals whose relational reasoner was not gated off.")
@@ -174,7 +177,7 @@ class StepObserver:
 
     def on_dispatch(self, n_served: int, n_pad: int, requested=None,
                     plan=None, gov=None, full_ewma=None,
-                    waits=()) -> Optional[dict]:
+                    waits=(), encoded_rows: int = 0) -> Optional[dict]:
         """Record one launched step; returns the open flight record.
 
         ``requested`` is the ``(fused, bucket_cap, decide)`` static args
@@ -182,12 +185,15 @@ class StepObserver:
         telemetry in :meth:`observe_step`); ``plan`` the latched
         ``KnobPlan`` (or None); ``gov`` a dict of the governor's state at
         dispatch (``level``/``slack``/``energy_ewma_mj``); ``waits`` each
-        served window's seconds in the engine's queue.
+        served window's seconds in the engine's queue; ``encoded_rows``
+        the valid feature rows the step encodes (0 on packed windows).
         """
         if self._c_steps is not None:
             self._c_steps.inc()
             self._c_windows.inc(n_served)
             self._c_pad.inc(n_pad)
+            if encoded_rows:
+                self._c_encoded.inc(encoded_rows)
             for w in waits:
                 self._h_queue.observe(w)
             if full_ewma is not None:

@@ -30,6 +30,7 @@ import jax
 
 #: the served step's phases, in the order the step runs them
 PHASES = (
+    "encode",        # q = sign(R z) of feature windows, bit-packed
     "prefix_scan",   # the hoisted bank-prefix XNOR-popcount kernel
     "policy",        # Alg. 1's bank and high-load choice, the word mask
     "full_select",   # the full path's accumulators from the scan

@@ -127,6 +127,7 @@ class AsyncStreamEngine(StreamEngine):
         store=None,
         snapshot_every: int = 1,
         fault_plan=None,
+        projection=None,
     ):
         if governor is not None and tracker is None:
             raise ValueError(
@@ -137,13 +138,17 @@ class AsyncStreamEngine(StreamEngine):
                 "serial (lax.map) lowering is host-sequential and cannot "
                 "shard the stream axis; use serial=False with a mesh")
         self._mesh = mesh if mesh is not None and mesh.devices.size > 1 else None
+        if self._mesh is not None and projection is not None:
+            raise ValueError(
+                "feature windows (projection=) are served on one device; "
+                "the stream-sharded step takes packed queries")
         super().__init__(cfg, im,
                          n_slots=shd.pad_stream_slots(n_slots, self._mesh),
                          jit=jit, serial=serial, fused=fused,
                          bucket_cap=bucket_cap, decide=decide,
                          metrics=metrics, flight=flight, tracer=tracer,
                          store=store, snapshot_every=snapshot_every,
-                         fault_plan=fault_plan)
+                         fault_plan=fault_plan, projection=projection)
         # async-specific phase spans (the sync step() spans are unused
         # here); each runs on exactly one daemon thread
         sp = (lambda name: span(name, metrics)) \
@@ -308,7 +313,8 @@ class AsyncStreamEngine(StreamEngine):
         arrival = self._tracker.now() if self._tracker else time.monotonic()
         ctx = (self._tracer.mint(stream_id, self._ENGINE)
                if self._tracer is not None else None)
-        window = (np.asarray(q_packed, np.uint32), np.asarray(valid, bool),
+        window = (np.asarray(q_packed, self._q0.dtype),
+                  np.asarray(valid, bool),
                   np.asarray(boxes, np.float32), fut, arrival, ctx)
         with self._work:
             self._pending[self._slot_of[stream_id]].append(window)
@@ -507,7 +513,8 @@ class AsyncStreamEngine(StreamEngine):
                                 plan=self._plan, gov=gov,
                                 full_ewma=(self._full_ewma if self._auto
                                            else None),
-                                waits=self._queue_waits(served, t_queue))
+                                waits=self._queue_waits(served, t_queue),
+                                encoded_rows=self._encoded_rows(v))
                             if rec is not None and self._tracer is not None:
                                 rec["ts_us"] = now_us()
                                 rec["queue_depth"] = int(qd.max())

@@ -176,6 +176,10 @@ class SyncDriver:
             target=self._pump, name="torr-syncdriver", daemon=True)
         self._thread.start()
 
+    @property
+    def feat_dim(self):
+        return self.engine.feat_dim
+
     def admit(self, stream_id, task_w, snapshot=None) -> int:
         with self._lock:
             if self._dead is not None:
@@ -271,7 +275,9 @@ class Gateway:
     ``front`` is anything with the admit/retire/submit surface —
     :class:`ServeSupervisor`, :class:`AsyncStreamEngine`, or a
     :class:`SyncDriver`; ``health()``/``retry_after_s()``/``heal()`` are
-    consulted when present. ``task_bank`` is the ``[n_tasks, M]`` matrix
+    consulted when present. A front whose ``feat_dim`` is set (an engine
+    built with a projection) takes feature windows: their body carries
+    ``z`` in place of ``q`` (``protocol.window_query``). ``task_bank`` is the ``[n_tasks, M]`` matrix
     of reasoner task-weight rows sessions select from.
     """
 
@@ -330,11 +336,14 @@ class Gateway:
                 "torr_telemetry_dropped_total", _DROPPED_HELP)
         else:
             self._m_dropped = None
+        # the window's queries: the features of an engine that encodes
+        # them on the device, else packed words
+        self._feat_dim = getattr(front, "feat_dim", None)
         # the window route's host spans (decorators: thread-safe across
         # the request threads); protocol's functions are looked up per call
         self._decode_window = span("gateway_decode", metrics)(
             lambda body: protocol.validate_window(
-                protocol.parse_json_body(body), self._cfg))
+                protocol.parse_json_body(body), self._cfg, self._feat_dim))
         self._encode_result = span("gateway_encode", metrics)(
             lambda seq, wout: json.dumps(
                 protocol.window_result_body(seq, wout)).encode())
@@ -752,7 +761,8 @@ class Gateway:
             raise ProtocolError(405, "bad_request", "GET only")
         self._count("config", 200, None)
         self._send_json(conn, 200, protocol.config_body(
-            self._cfg, len(self._task_bank), self.limits), keep)
+            self._cfg, len(self._task_bank), self.limits, self._feat_dim),
+            keep)
 
     def _h_session(self, conn, method, path, body, keep) -> None:
         if method == "POST" and path == "/v1/session":

@@ -22,7 +22,10 @@ Requests
 --------
 ``POST /v1/session``   ``{"tenant", "stream", "task", "rt"?}``
 ``POST /v1/window``    ``{"session", "seq", "q", "valid", "boxes",
-                         "deadline_ms"?}``
+                         "deadline_ms"?}``; a deployment that encodes on
+                       the device takes ``"z"`` (int8 ``[N_max, feat_dim]``
+                       features) in place of ``"q"``, as ``/v1/config``'s
+                       ``window`` names
 ``DELETE /v1/session/<tenant>/<stream>``
 
 Identifiers are ``[A-Za-z0-9_.-]{1,64}``; a session id is
@@ -191,10 +194,20 @@ class SessionOpen:
 class WindowRequest:
     session: str
     seq: int
-    q: np.ndarray        # uint32 [N_max, words]
+    q: np.ndarray        # uint32 [N_max, words], or the body's int8
+                         # [N_max, feat_dim] features ``z``
     valid: np.ndarray    # bool   [N_max]
     boxes: np.ndarray    # f32    [N_max, 4]
     deadline_s: Optional[float]   # per-request gateway wait budget
+
+
+def window_query(cfg, feat_dim: Optional[int] = None) -> tuple:
+    """``(field, dtype, shape)`` of a window's queries: packed words
+    ``"q"``, or int8 features ``"z"`` where the deployment encodes
+    ``feat_dim``-wide features on the device."""
+    if feat_dim is None:
+        return "q", np.dtype(np.uint32), (int(cfg.N_max), int(cfg.words))
+    return "z", np.dtype(np.int8), (int(cfg.N_max), int(feat_dim))
 
 
 def validate_session_open(body: dict, n_tasks: int) -> SessionOpen:
@@ -211,16 +224,19 @@ def validate_session_open(body: dict, n_tasks: int) -> SessionOpen:
     return SessionOpen(tenant=tenant, stream=stream, task=task, rt=rt)
 
 
-def validate_window(body: dict, cfg) -> WindowRequest:
+def validate_window(body: dict, cfg,
+                    feat_dim: Optional[int] = None) -> WindowRequest:
+    """A window body, its queries per :func:`window_query`."""
     sid = _require(body, "session", str)
     split_session_id(sid)
     seq = _require(body, "seq", int)
     if seq < 0:
         raise ProtocolError(400, "bad_request", "seq must be >= 0")
-    q = decode_array(_require(body, "q", dict,
-                              "field 'q' must be an encoded array"),
-                     dtype=np.uint32, shape=(cfg.N_max, cfg.words),
-                     field="q")
+    field, dtype, shape = window_query(cfg, feat_dim)
+    queries = decode_array(
+        _require(body, field, dict,
+                 f"field {field!r} must be an encoded array"),
+        dtype=dtype, shape=shape, field=field)
     valid = decode_array(_require(body, "valid", dict,
                                   "field 'valid' must be an encoded array"),
                          dtype=np.bool_, shape=(cfg.N_max,), field="valid")
@@ -239,7 +255,7 @@ def validate_window(body: dict, cfg) -> WindowRequest:
             raise ProtocolError(400, "bad_request",
                                 "deadline_ms must be in (0, 600000]")
         deadline_s = float(dl) / 1e3
-    return WindowRequest(session=sid, seq=seq, q=q, valid=valid,
+    return WindowRequest(session=sid, seq=seq, q=queries, valid=valid,
                          boxes=boxes, deadline_s=deadline_s)
 
 
@@ -260,7 +276,9 @@ def window_result_body(seq: int, wout) -> dict:
     }
 
 
-def config_body(cfg, n_tasks: int, limits) -> dict:
+def config_body(cfg, n_tasks: int, limits,
+                feat_dim: Optional[int] = None) -> dict:
+    field, dtype, shape = window_query(cfg, feat_dim)
     return {
         "protocol": PROTOCOL_VERSION,
         "N_max": int(cfg.N_max),
@@ -268,6 +286,8 @@ def config_body(cfg, n_tasks: int, limits) -> dict:
         "D": int(cfg.D),
         "M": int(cfg.M),
         "n_tasks": int(n_tasks),
+        "window": {"field": field, "dtype": str(dtype),
+                   "shape": list(shape)},
         "limits": {
             "max_body_bytes": int(limits.max_body_bytes),
             "rate_per_s": float(limits.rate_per_s),

@@ -13,6 +13,10 @@ Scheduling contract:
   * ``admit(stream_id, task_w)`` binds a stream to a free slot and resets
     that slot's cache (no cross-stream reuse leaks).
   * ``submit(stream_id, q_packed, valid, boxes)`` enqueues one window.
+    An engine built with a ``projection`` R (int8 ``[D, feat_dim]``,
+    device-resident) takes int8 features ``[N_max, feat_dim]`` in place of
+    the packed words, and its step encodes them on the device (the
+    pipeline's ``encode`` phase).
   * ``step()`` pops the head window of every busy slot, pads idle slots
     (valid all-False -> the pipeline's pad branch leaves their cache
     untouched), and returns {stream_id: (WindowOutput, WindowTelemetry)}.
@@ -112,10 +116,14 @@ class StreamEngine:
         store=None,
         snapshot_every: int = 1,
         fault_plan=None,
+        projection=None,
     ):
         self.cfg = cfg
         self.im = im
         self.n_slots = n_slots
+        # feature windows: R stays on the device, a traced step argument
+        self._projection = (None if projection is None
+                            else jnp.asarray(projection, jnp.int8))
         self._state: TorrState = pipeline.init_multi_stream_state(
             cfg, jnp.zeros((n_slots, cfg.M), jnp.float32)
         )
@@ -191,7 +199,10 @@ class StreamEngine:
         # machinery end-to-end
         self._fault = fault_plan
         # reusable host-side pad buffers for batch assembly
-        self._q0 = np.zeros((cfg.N_max, cfg.words), np.uint32)
+        self._q0 = (np.zeros((cfg.N_max, cfg.words), np.uint32)
+                    if projection is None else
+                    np.zeros((cfg.N_max, self._projection.shape[1]),
+                             np.int8))
         self._v0 = np.zeros((cfg.N_max,), bool)
         self._b0 = np.zeros((cfg.N_max, 4), np.float32)
 
@@ -259,7 +270,8 @@ class StreamEngine:
     # -- window flow --------------------------------------------------------
 
     def submit(self, stream_id, q_packed, valid, boxes) -> None:
-        """Enqueue one window (packed queries, validity, boxes) for a stream.
+        """Enqueue one window (packed queries, or the features an engine
+        with a projection takes; validity, boxes) for a stream.
 
         The window's trailing payload is ``(future, arrival, ctx)``, as in
         the async engine: no future here, the arrival time on the
@@ -269,13 +281,20 @@ class StreamEngine:
         slot = self._slot_of[stream_id]
         ctx = (self._tracer.mint(stream_id, self._ENGINE)
                if self._tracer is not None else None)
-        self._pending[slot].append((np.asarray(q_packed, np.uint32),
+        self._pending[slot].append((np.asarray(q_packed, self._q0.dtype),
                                     np.asarray(valid, bool),
                                     np.asarray(boxes, np.float32),
                                     None, time.monotonic(), ctx))
 
     def backlog(self, stream_id) -> int:
         return len(self._pending[self._slot_of[stream_id]])
+
+    @property
+    def feat_dim(self):
+        """The width of the features the engine takes, or None where it
+        takes packed queries."""
+        return None if self._projection is None \
+            else int(self._projection.shape[1])
 
     @property
     def busy(self) -> bool:
@@ -501,9 +520,16 @@ class StreamEngine:
         """``(args, kwargs)`` of the step over ``batch`` with the resolved
         ``(fused, bucket_cap, decide)``."""
         fused, bucket_cap, decide = resolved
-        return ((self._state, self.im, batch, self.cfg),
-                dict(serial=self._serial, plan=self._plan, fused=fused,
-                     bucket_cap=bucket_cap, decide=decide))
+        kw = dict(serial=self._serial, plan=self._plan, fused=fused,
+                  bucket_cap=bucket_cap, decide=decide)
+        if self._projection is not None:
+            kw["projection"] = self._projection
+        return (self._state, self.im, batch, self.cfg), kw
+
+    def _encoded_rows(self, v) -> int:
+        """The valid feature rows a step over validity ``v`` encodes (0 on
+        packed windows)."""
+        return 0 if self._projection is None else int(np.count_nonzero(v))
 
     def _dispatch(self, q, v, b, qd):
         """Launch one batched step (asynchronously) and advance the state."""
@@ -558,7 +584,8 @@ class StreamEngine:
                     len(served), self.n_slots - len(served),
                     requested=self._last_resolved, plan=self._plan,
                     full_ewma=self._full_ewma if self._auto else None,
-                    waits=self._queue_waits(served, t_dispatch))
+                    waits=self._queue_waits(served, t_dispatch),
+                    encoded_rows=self._encoded_rows(v))
                 if rec is not None and self._tracer is not None:
                     rec["ts_us"] = now_us()
                     rec["queue_depth"] = int(qd.max())

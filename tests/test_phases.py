@@ -28,8 +28,11 @@ def _compiled_text(**kw) -> str:
     im = random_item_memory(jax.random.PRNGKey(0), CFG)
     state = pipeline.init_multi_stream_state(
         CFG, jnp.zeros((S, CFG.M), jnp.float32))
+    queries = (jnp.zeros((S, CFG.N_max, CFG.feat_dim), jnp.int8)
+               if "projection" in kw else
+               jnp.zeros((S, CFG.N_max, CFG.words), jnp.uint32))
     batch = StreamBatch(
-        q_packed=jnp.zeros((S, CFG.N_max, CFG.words), jnp.uint32),
+        q_packed=queries,
         valid=jnp.zeros((S, CFG.N_max), bool),
         boxes=jnp.zeros((S, CFG.N_max, 4), jnp.float32),
         queue_depth=jnp.zeros((S,), jnp.int32))
@@ -52,11 +55,15 @@ def _fused_instructions(text) -> set:
     {},                                          # the served prefix step
     {"fused": "compact", "decide": "batched"},
     {"fused": "compact", "decide": "scan"},
+    # the served step of feature windows: encode first
+    {"projection": jnp.ones((CFG.D, CFG.feat_dim), jnp.int8)},
 ])
 def test_every_phase_of_the_step_is_in_its_table(kw):
     text = _compiled_text(**kw)
     table = phase_table(text)
-    assert set(table.values()) == set(PHASES)
+    # only a step given feature windows encodes them
+    want = set(PHASES) if "projection" in kw else set(PHASES) - {"encode"}
+    assert set(table.values()) == want
     assert set(table) <= set(_INSTRUCTION.findall(text))
     assert not set(table) & _fused_instructions(text)
 

@@ -219,3 +219,56 @@ def test_stream_sharded_step_compiles(topo, tpu_lowering):
     assert "tpu_custom_call" in text
     for collective in ("all-gather", "all-reduce", "all-to-all"):
         assert collective not in text
+
+
+@pytest.mark.parametrize("n", [ROWS, CFG.N_max])
+def test_int8_sign_project_pack_compiles(one_chip, n):
+    """The feature step's encode: int8 features against the int8 ±1
+    projection, int32 accumulation on the MXU."""
+    text = _compile(
+        lambda z, r: fused_window.sign_project_pack(z, r, interpret=False),
+        _spec(one_chip, (n, CFG.feat_dim), jnp.int8),
+        _spec(one_chip, (CFG.D, CFG.feat_dim), jnp.int8))
+    assert "tpu_custom_call" in text
+
+
+_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+
+
+def _encode_ops(text) -> dict:
+    """``{instruction: (result shape, opcode)}`` of the ops in the
+    ``encode`` phase."""
+    ops = {m.group(1): m.groups()[1:]
+           for m in map(_OP.match, text.splitlines()) if m is not None}
+    return {name: ops[name]
+            for name, ph in phases.phase_table(text).items()
+            if ph == "encode"}
+
+
+def test_only_the_feature_step_encodes(one_chip, tpu_lowering):
+    """The served packed step has no ``encode`` op; the feature step's
+    ``encode`` phase holds one kernel, ``sign_project_pack``, over all
+    S x N_max rows, before the prefix scan."""
+    state, im, batch = _step_args(lambda a, streamed: one_chip)
+    packed = _compile(
+        lambda st, m, b: pipeline.torr_stream_batch_step(
+            st, m, b, CFG, fused="prefix"), state, im, batch)
+    assert not _encode_ops(packed)
+    assert "sign_project_pack" not in packed
+
+    feats = jax.ShapeDtypeStruct((S, CFG.N_max, CFG.feat_dim), jnp.int8,
+                                 sharding=one_chip)
+    proj = _spec(one_chip, (CFG.D, CFG.feat_dim), jnp.int8)
+    text = _compile(
+        lambda st, m, b, r: pipeline.torr_stream_batch_step(
+            st, m, b, CFG, fused="prefix", projection=r),
+        state, im, StreamBatch(q_packed=feats, valid=batch.valid,
+                               boxes=batch.boxes,
+                               queue_depth=batch.queue_depth), proj)
+    encode = _encode_ops(text)
+    calls = [name for name, (_shape, op) in encode.items()
+             if op == "custom-call"]
+    assert len(calls) == 1 and "sign_project_pack" in calls[0], encode
+    assert encode[calls[0]][0].startswith(f"u32[{CFG.words},{ROWS}]")
+    # the words go on to the bank-prefix scan in the same executable
+    assert "bank_prefix_hamming" in text
